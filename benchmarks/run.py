@@ -9,10 +9,11 @@ file itself, not just in its git history).
 re-records just those suites).  ``--smoke`` runs the trajectory suites at
 tiny sizes as a wiring check — failures still abort loudly, but nothing is
 written to BENCH_kernels.json (smoke numbers are not perf claims).
-``--device-count N`` re-execs the driver with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` when the visible
-device count differs, so many-device benches (index_sharded) are
-reproducible from one flag on any single-host CPU box."""
+``--device-count N`` runs the benchmarks on N virtual CPU devices
+(``XLA_FLAGS=--xla_force_host_platform_device_count=N``; it needs
+``JAX_PLATFORMS=cpu``), so many-device benches (index_sharded) are
+reproducible from one flag on any single-host box.  JAX keeps its compiled programs in the persistent compile cache
+(`repro.runtime.compile_cache`)."""
 
 from __future__ import annotations
 
@@ -106,13 +107,12 @@ def _record_trajectory(trajectory: dict) -> None:
 
 
 def _ensure_device_count(argv: list[str]) -> None:
-    """`--device-count N`: re-exec with XLA_FLAGS forcing N virtual host
-    devices when the visible count differs.  Must run BEFORE anything
-    imports jax for itself — the backend binds the device count at first
-    import, so the only way to change it is a fresh process.  The env
-    sentinel stops a re-exec loop when the platform ignores the flag
-    (e.g. a real GPU backend): one attempt, then proceed honestly with
-    whatever jax.device_count() says."""
+    """`--device-count N`: give this process N virtual CPU devices by
+    setting XLA_FLAGS before JAX starts a backend.  Virtual host devices
+    exist only on the CPU platform, so it refuses to run unless
+    JAX_PLATFORMS=cpu is set: on a chip host the numbers it records are
+    then CPU numbers by the caller's own choice, never by a silent switch.
+    It never touches JAX itself."""
     n = None
     for i, arg in enumerate(argv):
         if arg == "--device-count":
@@ -121,28 +121,26 @@ def _ensure_device_count(argv: list[str]) -> None:
             n = int(argv[i + 1])
         elif arg.startswith("--device-count="):
             n = int(arg.split("=", 1)[1])
-    if n is None or n < 1:
-        if n is not None:
-            raise SystemExit(f"--device-count must be >= 1, got {n}")
+    if n is None:
         return
-    if os.environ.get("_REPRO_BENCH_DEVICES") == str(n):
-        return  # already re-exec'd once for this count
-    import jax
-
-    if jax.device_count() == n:
-        return
+    if n < 1:
+        raise SystemExit(f"--device-count must be >= 1, got {n}")
+    platforms = os.environ.get("JAX_PLATFORMS")
+    if platforms != "cpu":
+        raise SystemExit(
+            f"--device-count makes virtual CPU devices; it needs "
+            f"JAX_PLATFORMS=cpu, got {platforms!r}")
     flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
              if not f.startswith("--xla_force_host_platform_device_count")]
     flags.append(f"--xla_force_host_platform_device_count={n}")
-    env = dict(os.environ,
-               XLA_FLAGS=" ".join(flags),
-               _REPRO_BENCH_DEVICES=str(n))
-    sys.stdout.flush()
-    os.execve(sys.executable, [sys.executable] + sys.argv, env)
+    os.environ["XLA_FLAGS"] = " ".join(flags)
 
 
 def main() -> None:
     _ensure_device_count(sys.argv[1:])
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import bench_cluster, bench_dedup, bench_index, \
         bench_kernels, bench_paper, bench_serve
 

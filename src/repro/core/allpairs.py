@@ -54,6 +54,7 @@ wa + wb - 2*inner, returned as float32 so both metrics share one code path).
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 import jax
@@ -137,19 +138,21 @@ def _append_hits(carry, flat, n_hits, i0, j0, width, capacity):
     """Append this tile's hits to the (buf_i, buf_j, count) carry.
 
     Buffers carry `capacity` extra slack slots: each tile appends with one
-    dynamic_update_slice of length `capacity` starting at the running count;
-    slots past the tile's hit count hold garbage but are overwritten by the
-    next tile (its window starts exactly at the new count) and never escape
-    the final [:count] slice.  Rank r's hit lives at the first flat index
-    with cumsum == r: a log(tile) binary-search gather per output slot, far
+    dynamic_update_slice of length min(capacity, tile size) — a tile never
+    holds more hits than entries — starting at the running count; slots
+    past the tile's hit count hold garbage but are overwritten by the next
+    tile (its window starts exactly at the new count) and never escape the
+    final [:count] slice.  Rank r's hit lives at the first flat index with
+    cumsum == r: a log(tile) binary-search gather per output slot, far
     cheaper than scattering the whole tile into the buffer.  Tiles with no
     candidates skip extraction entirely.
     """
+    window = min(capacity, flat.shape[0])
 
     def extract(c):
         bi, bj, cnt = c
         csum = jnp.cumsum(flat)
-        ranks = jnp.arange(1, capacity + 1, dtype=csum.dtype)
+        ranks = jnp.arange(1, window + 1, dtype=csum.dtype)
         pos = jnp.searchsorted(csum, ranks)
         pos = jnp.minimum(pos, flat.shape[0] - 1)
         gi_v = (i0 + pos // width).astype(jnp.int32)
@@ -268,7 +271,7 @@ def _banded_pairs_impl(a_pp, threshold, *, n, block, width, capacity, metric,
     buf_len = 2 * capacity
     w_rows = packing.popcount_rows(a_pp).astype(jnp.float32)
     if logfree:
-        log_d = jnp.log1p(-1.0 / jnp.float32(d))
+        log_d = math.log1p(-1.0 / d)
         k_thr = jnp.float32(d) * jnp.exp(log_d * threshold * 0.25)
         radii = jnp.sqrt(jnp.maximum(1.0 - w_rows / d, 0.0))
 

@@ -95,8 +95,7 @@ def sketch_sparse_jnp(
 
     This is the oracle the fused Pallas kernel
     (repro.kernels.cabin_build_sparse) is tested against bit-for-bit, and
-    the fallback `sketch_sparse` uses when the sketch dim is not 128-aligned
-    or no accelerator is present.
+    the path `sketch_sparse` takes off-TPU.
     """
     bits = hashing.psi_bits(indices.astype(jnp.uint32), values, params.psi_seed)
     buckets = hashing.pi_buckets(indices.astype(jnp.uint32),
@@ -113,6 +112,24 @@ def sketch_sparse_jnp(
     return packing.pack_bits(out)
 
 
+def kernel_dispatch(sketch_dim: int, use_pallas: bool | None) -> bool:
+    """Whether Cabin sketching runs the fused Pallas kernels.
+
+    use_pallas=None picks the kernel exactly when JAX runs on a TPU.  The
+    kernels need sketch_dim % 128 == 0; a request for them — explicit, or
+    implied by running on a TPU — raises for any other dim instead of
+    quietly taking the jnp path.  use_pallas=False always takes it.
+    """
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    if use_pallas and sketch_dim % 128:
+        raise ValueError(
+            f"sketch_dim={sketch_dim} is not a multiple of 128, which the "
+            "Cabin kernels need; round d up (the theory gives a minimum d) "
+            "or pass use_pallas=False for the jnp path")
+    return bool(use_pallas)
+
+
 def sketch_sparse(
     params: CabinParams,
     indices: jnp.ndarray,
@@ -127,17 +144,13 @@ def sketch_sparse(
     0 = padding / missing (psi maps it to 0, so padded entries can share
     index 0 safely).
 
-    Dispatch: when the sketch dim is 128-aligned and a TPU is present (or the
-    kernel is explicitly requested via use_pallas=True, e.g. under
-    interpret=True in tests), the fused Pallas kernel
+    Dispatch (`kernel_dispatch`): on a TPU the fused Pallas kernel
     repro.kernels.cabin_build_sparse builds the packed sketch in one pass;
-    otherwise the jnp scatter-max reference path runs.  Both produce
-    bit-identical output.
+    elsewhere the jnp scatter-max reference path runs, unless the kernel is
+    requested explicitly via use_pallas=True (tests run it with
+    interpret=True).  Both produce bit-identical output.
     """
-    if use_pallas is None:
-        use_pallas = (jax.default_backend() == "tpu"
-                      and params.sketch_dim % 128 == 0)
-    if use_pallas and params.sketch_dim % 128 == 0:
+    if kernel_dispatch(params.sketch_dim, use_pallas):
         # lazy import: repro.kernels.* imports this module for CabinParams
         from repro.kernels.cabin_build_sparse import kernel as _sparse_kernel
 

@@ -24,21 +24,52 @@ has a Pallas TPU kernel twin in repro.kernels.hamming.
 
 from __future__ import annotations
 
+import math
+
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import packing
 
 _EPS = 1e-9
 
+# Cephes logf: log(1 + x) = x - x^2/2 + x^3 P(x) on [sqrt(1/2) - 1, sqrt(2) - 1]
+_LOG_POLY = (7.0376836292e-2, -1.1514610310e-1, 1.1676998740e-1,
+             -1.2420140846e-1, 1.4249322787e-1, -1.6668057665e-1,
+             2.0000714765e-1, -2.4999993993e-1, 3.3333331174e-1)
+_LN2_HI, _LN2_LO = 0.693359375, -2.12194440e-4
+
+
+def log_f32(y: jnp.ndarray) -> jnp.ndarray:
+    """Natural log of positive normal f32 values to about an ulp, from bit
+    operations, multiplies and adds only (Cephes logf), so XLA and Pallas
+    kernels compute it alike on every backend.  The TPU's native f32 log is
+    a fast approximation (relative error up to ~2e-4 for arguments near 1,
+    measured on a v5e) that the 2u - a - b cancellation below turns into
+    distance errors of ~1e-3."""
+    bits = jax.lax.bitcast_convert_type(y.astype(jnp.float32), jnp.int32)
+    mant = jax.lax.bitcast_convert_type((bits & 0x007FFFFF) | 0x3F800000,
+                                        jnp.float32)  # [1, 2)
+    big = mant >= np.float32(math.sqrt(2.0))
+    x = jnp.where(big, 0.5 * mant, mant) - 1.0  # exact
+    e = ((bits >> 23) - 127 + big.astype(jnp.int32)).astype(jnp.float32)
+    z = x * x
+    p = jnp.full_like(x, _LOG_POLY[0])
+    for c in _LOG_POLY[1:]:
+        p = p * x + c
+    r = p * x * z + _LN2_LO * e - 0.5 * z
+    return (x + r) + _LN2_HI * e
+
 
 def _safe_log1m(x: jnp.ndarray) -> jnp.ndarray:
     """log(1 - x), clamped: saturated sketches (x -> 1) clip to a full bin."""
-    return jnp.log(jnp.clip(1.0 - x, _EPS, 1.0))
+    return log_f32(jnp.clip(1.0 - x, _EPS, 1.0))
 
 
 def density_estimate(weight: jnp.ndarray, d: int) -> jnp.ndarray:
     """Estimate pre-sketch Hamming weight from sketch weight (BinSketch)."""
-    log_d = jnp.log1p(-1.0 / d)
+    log_d = math.log1p(-1.0 / d)
     return _safe_log1m(weight.astype(jnp.float32) / d) / log_d
 
 
@@ -61,7 +92,7 @@ def binhamming_from_stats(
     every distance against it; clamping degrades it gracefully to "as far
     as its observed support allows".
     """
-    log_d = jnp.log1p(-1.0 / d)
+    log_d = math.log1p(-1.0 / d)  # d is static: a host constant
     wu = wu.astype(jnp.float32)
     wv = wv.astype(jnp.float32)
     st = inner.astype(jnp.float32)
@@ -99,7 +130,7 @@ def inner_estimate(u: jnp.ndarray, v: jnp.ndarray, d: int) -> jnp.ndarray:
     wu = packing.popcount_rows(u)
     wv = packing.popcount_rows(v)
     st = packing.packed_inner(u, v)
-    log_d = jnp.log1p(-1.0 / d)
+    log_d = math.log1p(-1.0 / d)
     a_hat = _safe_log1m(wu.astype(jnp.float32) / d) / log_d
     b_hat = _safe_log1m(wv.astype(jnp.float32) / d) / log_d
     u_hat = _safe_log1m((wu + wv - st).astype(jnp.float32) / d) / log_d

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:
     return (n_stages - 1) / (n_stages + n_microbatches - 1)
@@ -81,7 +79,7 @@ def pipeline_apply(mesh: Mesh, stage_fn, stage_params, x_microbatches,
         buf, outs = jax.lax.fori_loop(0, total, body, (buf, outs))
         return outs[None]  # restore stage-leading dim
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=P(axis),

@@ -30,46 +30,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def current_mesh() -> Mesh | None:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is None or m.empty:
-            return None
-        return m
-    except Exception:
-        pass
-    # jax < 0.5: no abstract-mesh API; the ambient mesh entered via
-    # `with mesh:` lives in the legacy thread-resources env.
-    try:
-        from jax.interpreters import pxla
-
-        m = pxla.thread_resources.env.physical_mesh
-        if m is None or m.empty:
-            return None
-        return m
-    except Exception:
-        return None
-
-
-def set_mesh(mesh: Mesh):
-    """Context manager activating `mesh`: jax.sharding.set_mesh on new jax,
-    the Mesh object itself (legacy global-mesh context) on jax < 0.5."""
-    sm = getattr(jax.sharding, "set_mesh", None)
-    if sm is not None:
-        return sm(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map on new jax; jax.experimental.shard_map (check_rep) on
-    jax < 0.5.  Only the kwargs this repo uses are forwarded."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=check_vma)
+    """The ambient mesh set by `jax.sharding.set_mesh`, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def mesh_axis_names() -> tuple[str, ...]:
@@ -80,9 +43,9 @@ def mesh_axis_names() -> tuple[str, ...]:
 def mesh_devices(mesh: Mesh) -> list:
     """The mesh's devices as a flat list in mesh order — the per-shard
     placement the index partition layer keys on (shard s lives on
-    ``mesh_devices(mesh)[s]``).  Abstract meshes (jax >= 0.5's
-    get_abstract_mesh) carry no concrete devices; fall back to the process
-    device list, which is what an abstract mesh of the whole host means."""
+    ``mesh_devices(mesh)[s]``).  Abstract meshes (get_abstract_mesh) carry
+    no concrete devices; fall back to the process device list, which is
+    what an abstract mesh of the whole host means."""
     devs = getattr(mesh, "devices", None)
     if devs is not None:
         return [d for d in np.asarray(devs).flat]

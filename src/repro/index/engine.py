@@ -78,7 +78,7 @@ def compile_cache_entries() -> int:
     for fn in (allpairs._threshold_pairs_impl, allpairs._banded_pairs_impl,
                allpairs._argmin_rows_impl, allpairs._topk_rows_impl,
                allpairs._rowsum_impl, _cabin.sketch_dense_jit,
-               _cabin.sketch_sparse_jit, _store_mod._append_rows):
+               _cabin.sketch_sparse_jit, _store_mod._append_rows()):
         size = getattr(fn, "_cache_size", None)
         if callable(size):
             total += size()
@@ -113,7 +113,8 @@ class QueryEngine:
         `drift_window` ingested rows) crosses the density bound
         `theory.max_density_for_dim(d, drift_delta)` for the current sketch
         dim — the Theorem 1/2 accuracy cliff.  The new dim is
-        `theory.sketch_dim(percentile, drift_delta)`, same hash seeds.
+        `theory.sketch_dim(percentile, drift_delta)` rounded up to a
+        multiple of 128, same hash seeds.
     """
 
     def __init__(self, params: CabinParams, *, metric: str = "cham",
@@ -614,7 +615,9 @@ class QueryEngine:
         """Feed per-row density observations into the drift window; when
         the `drift_pct` percentile needs a bigger sketch dim than we have
         (theory.sketch_dim at `drift_delta`), auto-start a lazy migration
-        to that dim.  No-op unless auto_migrate."""
+        to that dim rounded up to a multiple of 128, the width the Cabin
+        kernels take (the theory's d is a minimum, so rounding up keeps
+        its bound).  No-op unless auto_migrate."""
         self._nnz_window.extend(int(c) for c in nnz_counts)
         if not self.auto_migrate or self._mig is not None:
             return
@@ -624,7 +627,7 @@ class QueryEngine:
             np.fromiter(self._nnz_window, np.int64), self.drift_pct))))
         need = theory.sketch_dim(p, self.drift_delta)
         if need > self.d:
-            self.migrate(d=need, drive="lazy")
+            self.migrate(d=-(-need // 128) * 128, drive="lazy")
 
     # -- result cache -------------------------------------------------------
 

@@ -53,6 +53,9 @@ serving stays exact through it.
 
 from __future__ import annotations
 
+import functools
+import weakref
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -480,15 +483,22 @@ class PartitionSet:
         (shard, kind, role, device) — read-time callbacks onto the live
         groups, so a fold or rebalance is visible at the next scrape.
         Re-registering the same labels (a successor set after a migration
-        publish) swaps the callback to the newest set."""
+        publish) swaps the callback to the newest set.  The callbacks hold
+        the set weakly: a set the engine dropped (after `shard()` changed
+        the labels) reads 0 and frees its device matrices."""
         if self.registry.is_null:
             return
+        ref = weakref.ref(self)
+
+        def rows(shard: int, kind: str) -> float:
+            live = ref()
+            return 0.0 if live is None else float(live._rows_of(shard, kind))
+
         for g in self._groups:
             dev = "host" if g.device is None else str(g.device)
             for kind in PARTITION_KINDS:
                 self.registry.gauge_fn(
-                    "partition_rows",
-                    (lambda s=g.shard, k=kind: float(self._rows_of(s, k))),
+                    "partition_rows", functools.partial(rows, g.shard, kind),
                     shard=str(g.shard), kind=kind, role=self.role,
                     device=dev)
 

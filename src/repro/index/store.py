@@ -35,6 +35,7 @@ costs one device concat, not a recompile or a re-sketch.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import NamedTuple
 
@@ -151,12 +152,15 @@ def _append_rows_fn(sk_buf, wt_buf, rows, start):
     return sk_buf, wt_buf
 
 
-# donate the buffers so accelerator appends update in place (no O(capacity)
-# copy per request); CPU has no donation — skip it there to avoid the
-# per-call "donated buffers were not usable" warning
-_append_rows = jax.jit(
-    _append_rows_fn,
-    donate_argnums=(0, 1) if jax.default_backend() != "cpu" else ())
+@functools.cache
+def _append_rows() -> "jax.stages.Wrapped":
+    """The jitted append, built at first use: it donates the buffers so
+    accelerator appends update in place (no O(capacity) copy per request);
+    CPU has no donation — skip it there to avoid the per-call "donated
+    buffers were not usable" warning.  Deciding at first use, not at
+    import, keeps `import repro.index` from initialising a JAX backend."""
+    donate = (0, 1) if jax.default_backend() != "cpu" else ()
+    return jax.jit(_append_rows_fn, donate_argnums=donate)
 
 
 class SketchStore:
@@ -414,7 +418,7 @@ class SketchStore:
             packed = packed[:kpad]
         if self._size + kpad > self.capacity:
             self._grow_to(pow2_bucket(self._size + kpad))
-        self._sk_buf, self._wt_buf = _append_rows(
+        self._sk_buf, self._wt_buf = _append_rows()(
             self._sk_buf, self._wt_buf, packed, jnp.int32(self._size))
         if self._placement is not None:
             self._sk_buf = self._place(self._sk_buf)
@@ -554,7 +558,7 @@ class SketchStore:
             kpad = pow2_bucket(size_b)
             if size_a + kpad > self.capacity:
                 self._grow_to(pow2_bucket(size_a + kpad))
-            self._sk_buf, self._wt_buf = _append_rows(
+            self._sk_buf, self._wt_buf = _append_rows()(
                 self._sk_buf, self._wt_buf, other._sk_buf[:kpad],
                 jnp.int32(size_a))
             if self._placement is not None:

@@ -1,20 +1,17 @@
 """Jit'd wrapper for fused sparse-Cabin sketch construction.
 
 Mirrors repro.kernels.cabin_build.ops: `use_pallas=None` auto-selects the
-compiled kernel on TPU for 128-aligned sketch dims, the jnp scatter-max
-reference otherwise; tests run the kernel with interpret=True on CPU.
+compiled kernel on TPU (which needs a 128-aligned sketch dim — an unaligned
+one raises there), the jnp scatter-max reference otherwise; tests run the
+kernel with interpret=True on CPU.
 """
 
 from __future__ import annotations
 
 import jax
 
-from repro.core.cabin import CabinParams, sketch_sparse_jnp
+from repro.core.cabin import CabinParams, kernel_dispatch, sketch_sparse_jnp
 from repro.kernels.cabin_build_sparse import kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def cabin_sketch_sparse(params: CabinParams, indices, values, *,
@@ -22,19 +19,18 @@ def cabin_sketch_sparse(params: CabinParams, indices, values, *,
                         interpret: bool | None = None):
     """Cabin sketches for padded-COO rows (N, m) x2 -> packed (N, w).
 
-    Uses the fused Pallas kernel when the sketch dim is 128-aligned (TPU) or
-    when explicitly requested (tests run it with interpret=True); otherwise
-    the jnp reference path.  Output is bit-identical either way.
+    Uses the fused Pallas kernel on TPU or when explicitly requested (tests
+    run it with interpret=True), the jnp reference path otherwise — the
+    `core.cabin.kernel_dispatch` rule.  Output is bit-identical either way.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu() and params.sketch_dim % 128 == 0
-    if use_pallas and params.sketch_dim % 128 == 0:
+    if kernel_dispatch(params.sketch_dim, use_pallas):
         return kernel.cabin_build_sparse(
             indices,
             values,
             d=params.sketch_dim,
             psi_seed=params.psi_seed,
             pi_seed=params.pi_seed,
-            interpret=bool(interpret if interpret is not None else not _on_tpu()),
+            interpret=bool(interpret if interpret is not None
+                           else jax.default_backend() != "tpu"),
         )
     return sketch_sparse_jnp(params, indices, values)

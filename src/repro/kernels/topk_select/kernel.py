@@ -5,31 +5,45 @@ store tiles past a query block and keeps the k best columns per query.  Run
 as separate passes — a pair-stats kernel producing an f32 distance tile in
 HBM, then a host/XLA select — every losing column (all but ~k of N) pays an
 HBM round-trip for a value that is immediately discarded.  This kernel fuses
-the two: the SWAR-popcount distance tile and the running k-best merge happen
-in one VMEM pass, so the only HBM writes are the (Q, k) results.
+the two: the distance tile and the running k-best merge happen in one VMEM
+pass, so the only HBM writes are the (Q, k) results.
 
-VMEM carry layout: the (BQ, k) values and indices OUTPUT tiles double as the
+VMEM carry layout: the (BQ, k) key and index OUTPUT tiles double as the
 carry — their index_map pins them to (i, 0) for every column step j, so with
 the column grid innermost they stay resident in VMEM across the whole sweep
 (same revisiting discipline as the hamming kernel's accumulator) and are
-flushed to HBM once per query tile.  Both live as full (value, index)-sorted
-rows; k is kept at its logical size (the store is sub-lane-width — Mosaic
-pads the trailing dim internally), so carry VMEM is 8·BQ·k bytes on top of
-the (BQ, W) + (BN, W) int32 input tiles.
+flushed to HBM once per query tile.  Both live as full (key, index)-sorted
+rows; k is kept at its logical size (Mosaic pads the trailing dim
+internally), so carry VMEM is 8·BQ·k bytes on top of the (BQ, W) + (BN, W)
+int32 input tiles.
 
-Merge: per tile, k compare-exchange rounds against the tile minimum.  Each
-round extracts the tile's lexicographic (distance, column) minimum — ties
-resolve to the LOWER column via an iota-masked second min — knocks it out of
-the tile, and inserts it into the sorted carry with a vectorised
-compare-exchange shift (count strictly-smaller carry entries, shift the tail
-right by one, place).  Equal-distance insertions land AFTER existing carry
-entries, whose columns are always lower (earlier tiles), so the carry is the
-exact (distance, column)-lexicographic k-best — bit-identical to
-core.allpairs._topk_rows_impl's stable merge, which tests pin.
+Distances: the popcount inner product <q, b> is taken on the MXU, one bit
+plane at a time — sum over s of ((q >> s) & 1) @ ((b >> s) & 1)^T with {0,1}
+operands (exact even at bf16 input precision) and f32 accumulation, exact
+for counts below 2^24 — so no
+(BQ, BN, W) broadcast is ever built.  Row weights come in precomputed.
+
+Ranking: each distance maps to an int32 sort key that orders exactly as
+jnp.argsort orders floats (-0.0 folded into +0.0, every NaN after +inf), so
+the kernel ranks a NaN distance where topk_select_ref does and every
+extraction names a real column.  The keys are the carry; the wrapper turns
+them back into distances.
+
+Merge: per column chunk, min(k, chunk) compare-exchange rounds against the
+chunk minimum.  Each round extracts the chunk's lexicographic (key, column)
+minimum — ties resolve to the LOWER column via an iota-masked second min —
+knocks it out of the chunk, and inserts it into the sorted carry with a
+vectorised compare-exchange shift (count strictly-smaller carry entries,
+shift the tail right by one, place).  Equal-key insertions land AFTER
+existing carry entries, whose columns are always lower (earlier chunks), so
+the carry is the exact (distance, column)-lexicographic k-best —
+bit-identical to core.allpairs._topk_rows_impl's stable merge, which tests
+pin.  Chunks and rounds are loops, not unrolled code, which bounds the
+compiled program at any tile size.
 
 Grid: (Q/BQ, N/BN) with the column dimension innermost; `m` (the traced
-valid-column count) rides in as a (1, 1) tile broadcast to every program so
-varying the live store size never recompiles.
+valid-column count) rides in SMEM so varying the live store size never
+recompiles.
 """
 
 from __future__ import annotations
@@ -39,62 +53,113 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.cham import binhamming_from_stats
-from repro.core.packing import pad_to_multiple, popcount32
+from repro.core.packing import pad_to_multiple, popcount_rows
+
+_SIGN_FREE = 0x7FFFFFFF
+_KEY_INF = 0x7F800000  # sort key of +inf
+_KEY_EMPTY = 0x7FFFFFFF  # above every real key, NaN included
+_CHUNK = 512  # columns per merge chunk
 
 
-def _tile_distances(qt, bt, metric: str, d: int) -> jnp.ndarray:
-    """(BQ, W) x (BN, W) packed -> (BQ, BN) f32, same formulas (and same
-    elementwise ops) as core.allpairs._tile_dist on the popcount backend."""
-    wa = jnp.sum(popcount32(qt), axis=-1)
-    wb = jnp.sum(popcount32(bt), axis=-1)
-    inner = jnp.sum(popcount32(qt[:, None, :] & bt[None, :, :]), axis=-1)
+def _sort_key(x: jnp.ndarray) -> jnp.ndarray:
+    """f32 -> int32 key, monotone in jnp.sort's float order."""
+    x = jnp.where(x == 0.0, 0.0, x)
+    x = jnp.where(x != x, jnp.nan, x)
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return i ^ ((i >> 31) & _SIGN_FREE)
+
+
+def _key_value(key: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of `_sort_key` (the flip is an involution)."""
+    return jax.lax.bitcast_convert_type(key ^ ((key >> 31) & _SIGN_FREE),
+                                        jnp.float32)
+
+
+def _inner(q: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """(BQ, W) x (BN, W) packed -> (BQ, BN) int32 bit inner products."""
+    nt = (((1,), (1,)), ((), ()))
+
+    def plane(s, acc):
+        qs = ((q >> s) & 1).astype(jnp.float32)
+        bs = ((b >> s) & 1).astype(jnp.float32)
+        return acc + jax.lax.dot_general(qs, bs, nt,
+                                         preferred_element_type=jnp.float32)
+
+    acc = jnp.zeros((q.shape[0], b.shape[0]), jnp.float32)
+    return jax.lax.fori_loop(0, 32, plane, acc).astype(jnp.int32)
+
+
+def _distances(wq, wb, inner, metric: str, d: int) -> jnp.ndarray:
+    """Same formulas as core.allpairs._tile_dist on exact integer stats."""
     if metric == "cham":
-        return 2.0 * binhamming_from_stats(wa[:, None], wb[None, :], inner, d)
+        return 2.0 * binhamming_from_stats(wq, wb, inner, d)
     if metric == "hamming":
-        return (wa[:, None] + wb[None, :] - 2 * inner).astype(jnp.float32)
+        return (wq + wb - 2 * inner).astype(jnp.float32)
     raise ValueError(f"unknown metric {metric!r}")
 
 
-def _topk_select_kernel(q_ref, b_ref, m_ref, vals_ref, idxs_ref, *,
-                        k, bn, metric, d):
+def _topk_select_kernel(m_ref, q_ref, wq_ref, b_ref, wb_ref, keys_ref,
+                        idxs_ref, *, k, bn, chunk, metric, d):
     """One (BQ, BN) column step of the running (BQ, k) select."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, jnp.inf)
+        keys_ref[...] = jnp.full_like(keys_ref, _KEY_EMPTY)
         idxs_ref[...] = jnp.full_like(idxs_ref, -1)
 
-    dist = _tile_distances(q_ref[...], b_ref[...], metric, d)
-    col = j * bn + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
-    dist = jnp.where(col < m_ref[0, 0], dist, jnp.inf)
-
-    vals = vals_ref[...]  # (BQ, k) ascending by (value, index)
-    idxs = idxs_ref[...]
-    kiota = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+    q = q_ref[...]
+    wq = wq_ref[...]  # (BQ, 1)
+    m = m_ref[0, 0]
+    bq = q.shape[0]
+    kiota = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
     big = jnp.int32(2**31 - 1)
-    for _ in range(k):
-        # lexicographic (value, column) tile minimum
-        tmin = jnp.min(dist, axis=1)
-        tidx = jnp.min(jnp.where(dist == tmin[:, None], col, big), axis=1)
-        dist = jnp.where(col == tidx[:, None], jnp.inf, dist)
-        # compare-exchange insertion: strictly-smaller carry entries stay,
-        # the tail shifts right one slot, the extracted pair drops in.  An
-        # insertion past the end (pos == k) leaves the carry untouched —
-        # masked +inf extractions can never evict the (+inf, -1) fillers,
-        # whose index -1 ranks them below every real column.
-        smaller = (vals < tmin[:, None]) | (
-            (vals == tmin[:, None]) & (idxs < tidx[:, None]))
-        pos = jnp.sum(smaller.astype(jnp.int32), axis=1)
-        shift_v = jnp.concatenate([vals[:, :1], vals[:, :-1]], axis=1)
-        shift_i = jnp.concatenate([idxs[:, :1], idxs[:, :-1]], axis=1)
-        keep = kiota < pos[:, None]
-        here = kiota == pos[:, None]
-        vals = jnp.where(keep, vals, jnp.where(here, tmin[:, None], shift_v))
-        idxs = jnp.where(keep, idxs, jnp.where(here, tidx[:, None], shift_i))
-    vals_ref[...] = vals
+
+    def merge_chunk(off, carry):
+        keys, idxs = carry  # (BQ, k) ascending by (key, index)
+        inner = _inner(q, b_ref[pl.ds(off, chunk), :])
+        dist = _distances(wq, wb_ref[:, pl.ds(off, chunk)], inner, metric, d)
+        col = (j * bn + off
+               + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1))
+        key = jnp.where(col < m, _sort_key(dist), _KEY_INF)
+
+        def extract(_, st):
+            key, keys, idxs = st
+            # lexicographic (key, column) chunk minimum
+            tmin = jnp.min(key, axis=1, keepdims=True)
+            tidx = jnp.min(jnp.where(key == tmin, col, big), axis=1,
+                           keepdims=True)
+            key = jnp.where(col == tidx, _KEY_EMPTY, key)
+            # compare-exchange insertion: strictly-smaller carry entries
+            # stay, the tail shifts right one slot, the extracted pair drops
+            # in.  An insertion past the end (pos == k) leaves the carry
+            # untouched; empty slots rank after every real column.
+            smaller = (keys < tmin) | ((keys == tmin) & (idxs < tidx))
+            pos = jnp.sum(smaller.astype(jnp.int32), axis=1, keepdims=True)
+            shift_k = jnp.concatenate([keys[:, :1], keys[:, :-1]], axis=1)
+            shift_i = jnp.concatenate([idxs[:, :1], idxs[:, :-1]], axis=1)
+            keep = kiota < pos
+            here = kiota == pos
+            keys = jnp.where(keep, keys, jnp.where(here, tmin, shift_k))
+            idxs = jnp.where(keep, idxs, jnp.where(here, tidx, shift_i))
+            return key, keys, idxs
+
+        _, keys, idxs = jax.lax.fori_loop(0, min(k, chunk), extract,
+                                          (key, keys, idxs))
+        return keys, idxs
+
+    carry = (keys_ref[...], idxs_ref[...])
+    if bn == chunk:  # one chunk: a static offset, whatever its width
+        keys, idxs = merge_chunk(0, carry)
+    else:  # lane-tile-aligned chunks
+        keys, idxs = jax.lax.fori_loop(
+            0, bn // chunk,
+            lambda c, cr: merge_chunk(pl.multiple_of(c * chunk, chunk), cr),
+            carry)
+    keys_ref[...] = keys
     idxs_ref[...] = idxs
 
 
@@ -122,28 +187,34 @@ def topk_select(
     assert q.ndim == 2 and b.ndim == 2 and q.shape[1] == b.shape[1]
     nq, w = q.shape
     bq_, bn_ = min(bq, nq), min(bn, b.shape[0])
+    chunk = _CHUNK if bn_ % _CHUNK == 0 else bn_
     q_p = pad_to_multiple(q, bq_, 0)
     b_p = pad_to_multiple(b, bn_, 0)
+    wq = popcount_rows(q_p)[:, None]
+    wb = popcount_rows(b_p)[None, :]
     grid = (q_p.shape[0] // bq_, b_p.shape[0] // bn_)
     m_arr = jnp.asarray(m, jnp.int32).reshape(1, 1)
 
-    vals, idxs = pl.pallas_call(
-        functools.partial(_topk_select_kernel, k=k, bn=bn_, metric=metric,
-                          d=d),
+    keys, idxs = pl.pallas_call(
+        functools.partial(_topk_select_kernel, k=k, bn=bn_, chunk=chunk,
+                          metric=metric, d=d),
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bq_, w), lambda i, j: (i, 0)),
+            pl.BlockSpec((bq_, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bn_, w), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, bn_), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bq_, k), lambda i, j: (i, 0)),
             pl.BlockSpec((bq_, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q_p.shape[0], k), jnp.float32),
+            jax.ShapeDtypeStruct((q_p.shape[0], k), jnp.int32),
             jax.ShapeDtypeStruct((q_p.shape[0], k), jnp.int32),
         ],
         interpret=interpret,
-    )(q_p, b_p, m_arr)
-    return vals[:nq], idxs[:nq]
+    )(m_arr, q_p, wq, b_p, wb)
+    keys, idxs = keys[:nq], idxs[:nq]
+    return jnp.where(idxs < 0, jnp.inf, _key_value(keys)), idxs

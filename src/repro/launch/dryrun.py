@@ -100,7 +100,7 @@ def lower_cell(cfg, shape, mesh, pcfg: ParallelConfig, moe_2d: bool = False):
     """Returns (lowered, aux_info)."""
     tcfg = TrainConfig()
     shd.set_moe_2d(moe_2d)
-    with shd.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params_abs = sp.abstract_params(cfg)
         pspecs = shd.param_specs(params_abs)
         psh = _named(mesh, pspecs)

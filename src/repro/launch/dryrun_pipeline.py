@@ -37,7 +37,6 @@ from repro.core.cabin import CabinParams, binem
 from repro.core.cham import binhamming_from_stats, cham_matrix
 from repro.core.packing import pack_bits, popcount32, unpack_bits
 from repro.launch import roofline as rl
-from repro.distributed import sharding as shd
 from repro.launch.mesh import make_production_mesh
 
 _log = logging.getLogger("repro.launch.dryrun_pipeline")
@@ -122,7 +121,7 @@ def run_variant(variant: str, multi_pod: bool, out_dir: str,
               "mesh": mesh_name, "tag": variant, "mode": "pipeline",
               "overrides": {}}
     try:
-        with shd.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             idx = jax.ShapeDtypeStruct((N_DOCS, MAX_NNZ), jnp.int32)
             val = jax.ShapeDtypeStruct((N_DOCS, MAX_NNZ), jnp.int32)
             dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)

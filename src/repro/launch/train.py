@@ -57,7 +57,7 @@ def main() -> None:
                           zero3=data_p > 1)
     tcfg = TrainConfig(total_steps=args.steps)
 
-    with shd.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = T.init_params(cfg, jax.random.PRNGKey(0))
         psh = jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), shd.param_specs(params),

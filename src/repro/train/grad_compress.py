@@ -97,19 +97,17 @@ def compress_tree_cross_pod(grads, mesh, error_feedback=None):
     psum-med over 'data' (pjit backward does this).  Returns
     (combined_grads, new_error_feedback).
     """
-    from jax.experimental.shard_map import shard_map
-
     corrected = ef_correct(grads, error_feedback)
 
     def comm(g):
         return cross_pod_sign_allreduce(g, "pod")
 
     def one(g):
-        fn = shard_map(
+        fn = jax.shard_map(
             comm, mesh=mesh,
             in_specs=P(),  # replicated within pod for optimizer-visible grads
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(g)
 

@@ -14,6 +14,7 @@ from repro.core.cham import (
     cham_matrix,
     density_estimate,
     inner_estimate,
+    log_f32,
 )
 from repro.core.theory import sketch_dim, theorem2_bound
 
@@ -124,6 +125,22 @@ def test_cham_identical_vectors_is_zero():
 # ---------------------------------------------------------------------------
 # Estimator internals
 # ---------------------------------------------------------------------------
+
+
+def test_log_f32_is_accurate_to_an_ulp():
+    """The estimator's TPU log: within about an ulp of float64 log over the
+    clamped range (1e-9, 1], exactly 0 at 1."""
+    rng = np.random.default_rng(0)
+    y = np.concatenate([rng.uniform(1e-9, 1.0, 50_000),
+                        rng.uniform(0.8, 1.0, 50_000),
+                        1.0 - np.arange(1, 4097) * 2.0**-24,
+                        [1.0, 1e-9, 0.5, np.sqrt(0.5), np.sqrt(2.0) / 2]]
+                       ).astype(np.float32)
+    got = np.asarray(log_f32(jnp.asarray(y))).astype(np.float64)
+    ref = np.log(y.astype(np.float64))
+    ulp = np.spacing(np.abs(ref).astype(np.float32)).astype(np.float64)
+    assert np.all(np.abs(got - ref) <= ulp)
+    assert got[y == 1.0].tolist() == [0.0] * int(np.sum(y == 1.0))
 
 
 @given(st.integers(16, 4096), st.integers(0, 2**31 - 1))

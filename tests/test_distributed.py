@@ -37,7 +37,7 @@ def test_param_specs_follow_rules():
 
         mesh = jax.make_mesh((2, 4), ("data", "model"))
         cfg = reduced_for_smoke(get_config("llama3_8b"))
-        with shd.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             params = abstract_params(cfg)
             specs = shd.param_specs(params)
         # embed table (512, 64): vocab over model, d over data
@@ -70,7 +70,7 @@ def test_sharded_train_step_runs_on_mesh():
         cfg = reduced_for_smoke(get_config("internlm2_1_8b"))
         pcfg = ParallelConfig(remat="block", sequence_parallel=True)
         tcfg = TrainConfig(z_loss=0.0)
-        with shd.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             params = T.init_params(cfg, jax.random.PRNGKey(0))
             psh = jax.tree_util.tree_map(
                 lambda s: NamedSharding(mesh, s), shd.param_specs(params),
@@ -102,7 +102,6 @@ def test_train_step_mesh_matches_single_device():
         from repro.configs.base import (ParallelConfig, TrainConfig,
                                         reduced_for_smoke)
         from repro.configs.registry import get_config
-        from repro.distributed import sharding as shd
         from repro.models import transformer as T
         from repro.train.train_step import loss_fn
 
@@ -123,7 +122,7 @@ def test_train_step_mesh_matches_single_device():
     """), n_devices=1)
     meshed = run_with_devices(code_tpl.format(SP="True", MESH="""
         mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with shd.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             loss, _ = jax.jit(lambda p, b: loss_fn(cfg, p, b, pcfg, tcfg))(params, batch)
     """), n_devices=8)
     l1 = float(single.split("LOSS=")[1].strip().split()[0])
@@ -178,8 +177,7 @@ def test_cross_pod_sign_compression_semantics():
         def f(g):
             return cross_pod_sign_allreduce(g[0], "pod")[None]
 
-        from repro.distributed.sharding import shard_map
-        out = shard_map(
+        out = jax.shard_map(
             f, mesh=mesh, in_specs=P(("pod", "data")),
             out_specs=P(("pod", "data")), check_vma=False)(stacked)
         out = np.asarray(out)

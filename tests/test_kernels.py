@@ -114,10 +114,13 @@ def test_cabin_ops_wrapper_dispatch():
     a = cabin_sketch(p, x, use_pallas=True, interpret=True)
     b = cabin_sketch(p, x, use_pallas=False)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # unaligned d falls back to reference silently
+    # off-TPU an unaligned d takes the reference path; a kernel request
+    # for it raises instead of quietly taking that path
     p2 = CabinParams.create(200, 100, seed=5)
     c = cabin_sketch(p2, x)
     assert c.shape == (6, 4)  # ceil(100/32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        cabin_sketch(p2, x, use_pallas=True, interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -191,10 +194,26 @@ def test_cabin_sparse_ops_wrapper_dispatch():
     b = cabin_sketch_sparse(p, jnp.asarray(idx), jnp.asarray(val),
                             use_pallas=False)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # unaligned d falls back to the jnp reference silently
+    # off-TPU an unaligned d takes the jnp reference; a kernel request for
+    # it raises
     p2 = CabinParams.create(3000, 100, seed=5)
     c = cabin_sketch_sparse(p2, jnp.asarray(idx), jnp.asarray(val))
     assert c.shape == (6, 4)  # ceil(100/32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        cabin_sketch_sparse(p2, jnp.asarray(idx), jnp.asarray(val),
+                            use_pallas=True, interpret=True)
+
+
+def test_kernel_dispatch_on_tpu_refuses_unaligned_dims(monkeypatch):
+    """On a TPU the kernels are due: an unaligned sketch dim raises there
+    rather than taking the jnp path unnoticed."""
+    from repro.core import cabin
+
+    monkeypatch.setattr(cabin.jax, "default_backend", lambda: "tpu")
+    assert cabin.kernel_dispatch(4096, None)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        cabin.kernel_dispatch(100, None)
+    assert not cabin.kernel_dispatch(100, False)
 
 
 def test_sketch_sparse_core_dispatch_bit_identical():

@@ -340,6 +340,43 @@ def test_drift_auto_trigger_and_auto_publish():
     assert np.array_equal(a, b) and np.array_equal(av, bv)
 
 
+def test_drift_migration_dim_suits_the_tpu_kernels(monkeypatch):
+    """A drift migration picks a dim the Cabin kernels take: on a TPU,
+    where an unaligned dim raises, sparse ingest keeps working after the
+    engine migrates itself.  Dispatch is decided as on a TPU; the kernel
+    then runs in interpret mode."""
+    from repro.core import cabin
+
+    decide = cabin.kernel_dispatch
+
+    def as_on_tpu(sketch_dim, use_pallas):
+        with monkeypatch.context() as m:
+            m.setattr(cabin.jax, "default_backend", lambda: "tpu")
+            return decide(sketch_dim, use_pallas)
+
+    monkeypatch.setattr(cabin, "kernel_dispatch", as_on_tpu)
+    # seeds of its own: the sketch jit must trace here, not hit a cache
+    p = CabinParams(n_dims=N_DIMS, sketch_dim=128, psi_seed=71, pi_seed=72)
+    eng = QueryEngine(p, auto_migrate=True, drift_delta=0.2,
+                      drift_window=64, cache_entries=0)
+    bound = theory.max_density_for_dim(128, 0.2)
+    rng = np.random.default_rng(13)
+    n, m = 96, bound + 8
+    idx = np.stack([rng.choice(N_DIMS, size=m, replace=False)
+                    for _ in range(n)]).astype(np.int32)
+    val = rng.integers(1, 6, size=(n, m)).astype(np.int32)
+    eng.add_sparse(idx[:64], val[:64])
+    assert eng.migrating
+    target = eng.migration.new_spec.d
+    ids = eng.add_sparse(idx[64:], val[64:])  # lands in the fresh tier
+    assert theory.sketch_dim(m, 0.2) % 128, "the theory's dim is unaligned"
+    assert target % 128 == 0 and target >= theory.sketch_dim(m, 0.2)
+    eng.migrate_all()
+    assert eng.d == target and len(eng) == n
+    top, _ = eng.topk((idx[64:67], val[64:67]), 1)
+    assert np.array_equal(top[:, 0], ids[:3])
+
+
 def test_auto_migrate_requires_keep_raw():
     with pytest.raises(ValueError, match="keep_raw"):
         QueryEngine(P_OLD, keep_raw=False, auto_migrate=True)

@@ -244,6 +244,25 @@ def test_partition_gauges_and_merge_span_shapes():
     assert "partition.merge" in names
 
 
+def test_reshard_frees_the_old_layout():
+    """A gauge whose labels the new topology no longer has must not keep
+    the old layout, and with it a device copy of the corpus, alive."""
+    import gc
+    import weakref
+
+    eng = QueryEngine(P, band_rows=8, cache_entries=0)
+    eng.add_dense(X[:24])
+    eng.shard(n_shards=2)
+    eng.topk(QUERIES, 4)
+    old = weakref.ref(eng.sync_layout())
+    eng.shard(n_shards=1)
+    eng.topk(QUERIES, 4)
+    gc.collect()
+    assert old() is None
+    if not eng.obs.is_null:  # shard 1's gauge now reads an empty layout
+        assert 'shard="1"} 0' in eng.render_prom()
+
+
 # ---------------------------------------------------------------------------
 # crash safety: shard.rebalance is a derived-state point (satellite)
 # ---------------------------------------------------------------------------

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import allpairs
+from repro.kernels.topk_select import kernel as topk_kernel
 from repro.kernels.topk_select.kernel import topk_select as topk_select_kernel
 from repro.kernels.topk_select.ops import topk_select
 from repro.kernels.topk_select.ref import topk_select_ref
@@ -48,17 +49,33 @@ def _check(metric, kv, ki, rv, ri):
         (1, 1, 1, 1, 8, 8),
         (9, 37, 8, 5, 4, 8),       # ragged: padding on every axis
         (16, 64, 8, 3, 8, 16),     # exact tiling
-        (33, 70, 9, 7, 16, 32),
+        (33, 70, 9, 7, 16, 32),    # W = 9 words: d = 288 bits
         (5, 12, 4, 12, 8, 4),      # k == n: every column is a winner
     ],
 )
 def test_topk_select_shapes(metric, q, n, w, k, bq, bn):
     a = _rows(q, w)
     b = _rows(n, w)
-    kv, ki = topk_select_kernel(a, b, n, k, metric=metric, d=D, bq=bq, bn=bn,
+    d = max(D, 32 * w)  # a packed row never holds more bits than d
+    kv, ki = topk_select_kernel(a, b, n, k, metric=metric, d=d, bq=bq, bn=bn,
                                 interpret=True)
-    rv, ri = topk_select_ref(a, b, k, d=D, metric=metric)
+    rv, ri = topk_select_ref(a, b, k, d=d, metric=metric)
     _check(metric, kv, ki, rv, ri)
+
+
+def test_topk_select_sort_key_ranks_like_argsort():
+    """The kernel ranks distances by an int32 key that orders exactly as
+    argsort orders floats — -0.0 with +0.0, every NaN after +inf — so a NaN
+    distance lands where topk_select_ref puts it, and the key maps back to
+    the same value."""
+    x = np.array([np.nan, 3.0, -0.0, np.inf, 0.0, -np.inf, 1e-30, -2.5,
+                  np.nan, 7.5], np.float32)
+    keys = np.asarray(topk_kernel._sort_key(jnp.asarray(x)))
+    np.testing.assert_array_equal(np.argsort(keys, kind="stable"),
+                                  np.argsort(x, kind="stable"))
+    assert keys.max() < topk_kernel._KEY_EMPTY  # an empty slot ranks last
+    back = np.asarray(topk_kernel._key_value(jnp.asarray(keys)))
+    np.testing.assert_array_equal(back, np.where(x == 0.0, 0.0, x))
 
 
 @pytest.mark.parametrize("metric", ["cham", "hamming"])
