@@ -60,6 +60,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import packing
 from repro.core.cham import binhamming_from_stats
 
@@ -223,23 +224,28 @@ def _threshold_pairs_impl(a_p, b_p, offsets, threshold, n, m, *, block,
         def compute(carry):
             a_blk = jax.lax.dynamic_slice(a_p, (i0, 0), (block, a_p.shape[1]))
             b_blk = jax.lax.dynamic_slice(b_p, (j0, 0), (block, b_p.shape[1]))
-            dist = _tile_dist(a_blk, b_blk, d, metric, mode)
+            with jax.named_scope("allpairs.tile_dist"):
+                dist = _tile_dist(a_blk, b_blk, d, metric, mode)
             gi = i0 + row_iota
             gj = j0 + col_iota
             mask = (dist < threshold) & (gi < n) & (gj < m)
             if symmetric:
                 mask &= gi < gj
             flat = mask.ravel().astype(jnp.int32)
-            return _append_hits(carry, flat, jnp.sum(flat), i0, j0, block,
-                                capacity)
+            with jax.named_scope("allpairs.append_hits"):
+                return _append_hits(carry, flat, jnp.sum(flat), i0, j0,
+                                    block, capacity)
 
         return jax.lax.cond(prunable, lambda c: c, compute, carry)
 
     buf_i = jnp.full((buf_len,), -1, jnp.int32)
     buf_j = jnp.full((buf_len,), -1, jnp.int32)
     count = jnp.int32(0)
-    buf_i, buf_j, count = jax.lax.fori_loop(
-        0, n_tiles, body, (buf_i, buf_j, count))
+    # named scopes are HLO metadata: they name the tile loop's device ops
+    # in a profile and leave the compiled program unchanged
+    with jax.named_scope("allpairs.threshold_scan"):
+        buf_i, buf_j, count = jax.lax.fori_loop(
+            0, n_tiles, body, (buf_i, buf_j, count))
     return buf_i, buf_j, count
 
 
@@ -400,76 +406,80 @@ def threshold_pairs(
     per-row sketch Hamming weights the caller already has (skips one
     device popcount + host sync).
     """
-    symmetric = b is None
-    if symmetric and (n_valid is not None or m_valid is not None):
-        raise ValueError("n_valid/m_valid require an explicit b "
-                         "(asymmetric scan)")
-    a = jnp.asarray(a)
-    b_arr = a if symmetric else jnp.asarray(b)
-    n = a.shape[0] if n_valid is None else n_valid
-    m = b_arr.shape[0] if m_valid is None else m_valid
-    if not (0 <= n <= a.shape[0] and 0 <= m <= b_arr.shape[0]):
-        raise ValueError(f"n_valid/m_valid ({n}, {m}) outside the supplied "
-                         f"rows ({a.shape[0]}, {b_arr.shape[0]})")
-    if n == 0 or m == 0:
-        return np.zeros((0, 2), np.int32)
-    # block and capacity are STATIC jit args of the impls: derive block from
-    # the (bucketed) array shapes and round capacity to a power of two, so
-    # callers whose valid counts drift by a few rows per call (the index
-    # engine's radius path under add/remove churn) reuse compiled graphs
-    block = max(1, min(block, max(a.shape[0], b_arr.shape[0])))
-    if capacity is None:
-        capacity = max(4096, 8 * max(n, m))
-    capacity = packing.pow2_bucket(capacity)
-    mode = _auto_mode(mode)
+    with obs.span("allpairs.threshold_pairs"):
+        symmetric = b is None
+        if symmetric and (n_valid is not None or m_valid is not None):
+            raise ValueError("n_valid/m_valid require an explicit b "
+                             "(asymmetric scan)")
+        a = jnp.asarray(a)
+        b_arr = a if symmetric else jnp.asarray(b)
+        n = a.shape[0] if n_valid is None else n_valid
+        m = b_arr.shape[0] if m_valid is None else m_valid
+        if not (0 <= n <= a.shape[0] and 0 <= m <= b_arr.shape[0]):
+            raise ValueError(f"n_valid/m_valid ({n}, {m}) outside the "
+                             f"supplied rows ({a.shape[0]}, "
+                             f"{b_arr.shape[0]})")
+        if n == 0 or m == 0:
+            return np.zeros((0, 2), np.int32)
+        # block and capacity are STATIC jit args of the impls: derive block
+        # from the (bucketed) array shapes and round capacity to a power of
+        # two, so callers whose valid counts drift by a few rows per call
+        # (the index engine's radius path under add/remove churn) reuse
+        # compiled graphs
+        block = max(1, min(block, max(a.shape[0], b_arr.shape[0])))
+        if capacity is None:
+            capacity = max(4096, 8 * max(n, m))
+        capacity = packing.pow2_bucket(capacity)
+        mode = _auto_mode(mode)
 
-    def run_with_capacity(run, capacity):
-        # overflow -> transparent re-run with a doubled (recompiled) buffer
-        while True:
-            bi, bj, cnt = run(capacity)
-            cnt = int(cnt)
-            if cnt <= capacity:
-                return np.stack(
-                    [np.asarray(bi)[:cnt], np.asarray(bj)[:cnt]], axis=1)
-            capacity = packing.pow2_bucket(max(2 * capacity, cnt))
+        def run_with_capacity(run, capacity):
+            # overflow -> transparent re-run with a doubled (recompiled) buffer
+            while True:
+                bi, bj, cnt = run(capacity)
+                cnt = int(cnt)
+                if cnt <= capacity:
+                    return np.stack(
+                        [np.asarray(bi)[:cnt], np.asarray(bj)[:cnt]], axis=1)
+                capacity = packing.pow2_bucket(max(2 * capacity, cnt))
 
-    if symmetric and sorted_by_weight:
-        if weights is None:
-            weights = np.asarray(packing.popcount_rows(a))
-        if np.any(np.diff(weights) < 0):
-            raise ValueError("sorted_by_weight=True but rows are not sorted "
-                             "by sketch weight")
-        scores = prune_score_host(weights, d, metric)
-        factor = prune_factor(metric)
-        width = _band_width(scores, n, block, threshold, factor)
-        n_pad = ((n + block - 1) // block) * block
-        a_pp = jnp.pad(a, ((0, n_pad + width - n), (0, 0)))
-        # log-free inner-product test needs the estimator unclamped
-        logfree = metric == "cham" and int(weights.max(initial=0)) < d
+        if symmetric and sorted_by_weight:
+            if weights is None:
+                weights = np.asarray(packing.popcount_rows(a))
+            if np.any(np.diff(weights) < 0):
+                raise ValueError("sorted_by_weight=True but rows are not "
+                                 "sorted by sketch weight")
+            scores = prune_score_host(weights, d, metric)
+            factor = prune_factor(metric)
+            width = _band_width(scores, n, block, threshold, factor)
+            n_pad = ((n + block - 1) // block) * block
+            a_pp = jnp.pad(a, ((0, n_pad + width - n), (0, 0)))
+            # log-free inner-product test needs the estimator unclamped
+            logfree = metric == "cham" and int(weights.max(initial=0)) < d
+            return run_with_capacity(
+                lambda cap: _banded_pairs_impl(
+                    a_pp, jnp.float32(threshold), n=n, block=block,
+                    width=width, capacity=cap, metric=metric, mode=mode, d=d,
+                    logfree=logfree),
+                capacity)
+
+        a_p = _pad_rows(a, block)
+        b_p = a_p if symmetric else _pad_rows(b_arr, block)
+        nb_a = a_p.shape[0] // block
+        nb_b = b_p.shape[0] // block
+        if symmetric:
+            offs = [(i * block, j * block)
+                    for i in range(nb_a) for j in range(i, nb_b)]
+        else:
+            offs = [(i * block, j * block)
+                    for i in range(nb_a) for j in range(nb_b)]
+        offsets = jnp.asarray(offs, dtype=jnp.int32)
+
         return run_with_capacity(
-            lambda cap: _banded_pairs_impl(
-                a_pp, jnp.float32(threshold), n=n, block=block, width=width,
-                capacity=cap, metric=metric, mode=mode, d=d, logfree=logfree),
+            lambda cap: _threshold_pairs_impl(
+                a_p, b_p, offsets, jnp.float32(threshold), jnp.int32(n),
+                jnp.int32(m), block=block, capacity=cap, symmetric=symmetric,
+                metric=metric, mode=mode, d=d),
             capacity)
-
-    a_p = _pad_rows(a, block)
-    b_p = a_p if symmetric else _pad_rows(b_arr, block)
-    nb_a = a_p.shape[0] // block
-    nb_b = b_p.shape[0] // block
-    if symmetric:
-        offs = [(i * block, j * block)
-                for i in range(nb_a) for j in range(i, nb_b)]
-    else:
-        offs = [(i * block, j * block)
-                for i in range(nb_a) for j in range(nb_b)]
-    offsets = jnp.asarray(offs, dtype=jnp.int32)
-
-    return run_with_capacity(
-        lambda cap: _threshold_pairs_impl(
-            a_p, b_p, offsets, jnp.float32(threshold), jnp.int32(n),
-            jnp.int32(m), block=block, capacity=cap, symmetric=symmetric,
-            metric=metric, mode=mode, d=d),
-        capacity)
 
 
 # ---------------------------------------------------------------------------
@@ -708,101 +718,122 @@ def topk_rows_banded(a, b, k: int, *, d: int, q_scores: np.ndarray,
     if stats_out is not None:
         # filled below; pre-set so early returns still report a full record
         stats_out.update(n_bands=len(band_lo), bands_visited=0,
-                         rows_visited=0, early_stop=False,
+                         rows_visited=0, rounds=0, early_stop=False,
                          partial=False, cert_gap=0.0)
     if q == 0 or k == 0:
         return np.zeros((q, 0), np.int64), np.zeros((q, 0), np.float32)
-    q_scores = np.asarray(q_scores, np.float64)
-    factor = prune_factor(metric)
-    n_bands = len(band_lo)
-    # per-(query, band) weight-bound gaps; visit priority = nearest first
-    gap = np.maximum(np.maximum(band_lo[None, :] - q_scores[:, None],
-                                q_scores[:, None] - band_hi[None, :]), 0.0)
-    if init_kth is not None:
-        init_kth = np.asarray(init_kth, np.float32)[:q]
-        if np.all(factor * gap >= init_kth[:, None] + PRUNE_MARGIN):
-            # every band is already outside the cross-partition bound:
-            # nothing here can enter the merged top-k, skip the walk
-            if stats_out is not None:
-                stats_out["early_stop"] = True
-            return np.zeros((q, 0), np.int64), np.zeros((q, 0), np.float32)
-    band_gap = gap.min(axis=0)
-    visit = np.argsort(band_gap, kind="stable")
-
-    best_v = np.full((q, k), np.inf, np.float32)
-    best_key = np.full((q, k), KBEST_KEY_PAD, np.int64)
-    best_pos = np.full((q, k), -1, np.int64)
-
-    def band_range(bb: int) -> np.ndarray:
-        return np.arange(bb * band_rows, min((bb + 1) * band_rows, n_valid))
-
-    ptr = 0
-    visited_rows = 0
-    while ptr < n_bands:
-        take = [visit[ptr]]
-        ptr += 1
-        if visited_rows == 0:
-            # round 1: every band the weight bound cannot separate from some
-            # query (gap == 0) — the bands the answers almost surely live in
-            while ptr < n_bands and band_gap[visit[ptr]] <= 0.0:
-                take.append(visit[ptr])
-                ptr += 1
-        else:
-            target = max(visited_rows, band_rows)  # geometric expansion
-            cnt = len(band_range(take[0]))
-            while ptr < n_bands and cnt < target:
-                take.append(visit[ptr])
-                cnt += len(band_range(visit[ptr]))
-                ptr += 1
-        rows = np.concatenate([band_range(bb) for bb in take])
-        if alive is not None:
-            rows = rows[alive[rows]]  # tombstoned rows never reach a tile
-        visited_rows += len(rows)
-        if len(rows):
-            keys = rows if order_by is None else np.asarray(order_by)[rows]
-            rows = rows[np.argsort(keys, kind="stable")]  # cols in key order
-            sub = packing.padded_take(b, rows)
-            kk = min(k, len(rows))
-            pos_c, val_c = topk_rows(a, sub, kk, d=d, metric=metric,
-                                     block=block, mode=mode,
-                                     m_valid=len(rows))
-            gpos = rows[pos_c[:q]]
-            gkey = gpos if order_by is None else np.asarray(order_by)[gpos]
-            if kk < k:  # pad the chunk's candidate list to k columns
-                padw = ((0, 0), (0, k - kk))
-                val_c = np.pad(val_c[:q], padw, constant_values=np.inf)
-                gpos = np.pad(gpos, padw, constant_values=-1)
-                gkey = np.pad(gkey, padw, constant_values=KBEST_KEY_PAD)
-            else:
-                val_c = val_c[:q]
-            best_v, best_key, best_pos = kbest_lex_merge(
-                k, np.concatenate([best_v, val_c], axis=1),
-                np.concatenate([best_key, gkey], axis=1),
-                np.concatenate([best_pos, gpos], axis=1))
-        if ptr >= n_bands:
-            break
-        kth = best_v[:, k - 1]
+    # one span for the walk; each round is four: `allpairs.walk.plan`
+    # (bands, rows, key order), `.gather` (the padded device take),
+    # `.score` (topk_rows up to its host copy: where the host waits on
+    # the device) and `.merge` (the k-best merge and the certificate)
+    with obs.span("allpairs.walk", bands=len(band_lo), q=q, k=k):
+        q_scores = np.asarray(q_scores, np.float64)
+        factor = prune_factor(metric)
+        n_bands = len(band_lo)
+        # per-(query, band) weight-bound gaps; visit priority = nearest first
+        gap = np.maximum(np.maximum(band_lo[None, :] - q_scores[:, None],
+                                    q_scores[:, None] - band_hi[None, :]), 0.0)
         if init_kth is not None:
-            kth = np.minimum(kth, init_kth)
-        bound = factor * gap[:, visit[ptr:]]
-        if np.all(bound >= kth[:, None] + PRUNE_MARGIN):
-            if stats_out is not None:
-                stats_out["early_stop"] = True
-            break
-        if deadline is not None and deadline.expired:
-            # budget exhausted before the certificate closed: stop here
-            # and report the residual gap — the distance the kth bound
-            # would have to move for the partial answer to be provably
-            # exact (inf when fewer than k candidates were even seen)
-            if stats_out is not None:
-                stats_out["partial"] = True
-                stats_out["cert_gap"] = float(np.max(np.maximum(
-                    kth[:, None] + PRUNE_MARGIN - bound, 0.0)))
-            break
-    if stats_out is not None:
-        stats_out["bands_visited"] = ptr
-        stats_out["rows_visited"] = visited_rows
-    return best_pos, best_v
+            init_kth = np.asarray(init_kth, np.float32)[:q]
+            if np.all(factor * gap >= init_kth[:, None] + PRUNE_MARGIN):
+                # every band is already outside the cross-partition bound:
+                # nothing here can enter the merged top-k, skip the walk
+                if stats_out is not None:
+                    stats_out["early_stop"] = True
+                return np.zeros((q, 0), np.int64), np.zeros((q, 0), np.float32)
+        band_gap = gap.min(axis=0)
+        visit = np.argsort(band_gap, kind="stable")
+
+        best_v = np.full((q, k), np.inf, np.float32)
+        best_key = np.full((q, k), KBEST_KEY_PAD, np.int64)
+        best_pos = np.full((q, k), -1, np.int64)
+
+        def band_range(bb: int) -> np.ndarray:
+            return np.arange(bb * band_rows,
+                             min((bb + 1) * band_rows, n_valid))
+
+        ptr = 0
+        visited_rows = 0
+        rounds = 0
+        while ptr < n_bands:
+            rounds += 1
+            with obs.span("allpairs.walk.plan", round=rounds):
+                take = [visit[ptr]]
+                ptr += 1
+                if visited_rows == 0:
+                    # round 1: every band the weight bound cannot separate
+                    # from some query (gap == 0) — the bands the answers
+                    # almost surely live in
+                    while ptr < n_bands and band_gap[visit[ptr]] <= 0.0:
+                        take.append(visit[ptr])
+                        ptr += 1
+                else:
+                    target = max(visited_rows, band_rows)  # geometric growth
+                    cnt = len(band_range(take[0]))
+                    while ptr < n_bands and cnt < target:
+                        take.append(visit[ptr])
+                        cnt += len(band_range(visit[ptr]))
+                        ptr += 1
+                rows = np.concatenate([band_range(bb) for bb in take])
+                if alive is not None:
+                    rows = rows[alive[rows]]  # tombstones reach no tile
+                visited_rows += len(rows)
+                if len(rows):
+                    keys = (rows if order_by is None
+                            else np.asarray(order_by)[rows])
+                    rows = rows[np.argsort(keys, kind="stable")]  # key order
+            if len(rows):
+                with obs.span("allpairs.walk.gather", rows=len(rows)):
+                    sub = packing.padded_take(b, rows)
+                kk = min(k, len(rows))
+                with obs.span("allpairs.walk.score", rows=len(rows)):
+                    pos_c, val_c = topk_rows(a, sub, kk, d=d, metric=metric,
+                                             block=block, mode=mode,
+                                             m_valid=len(rows))
+            with obs.span("allpairs.walk.merge", round=rounds):
+                if len(rows):
+                    gpos = rows[pos_c[:q]]
+                    gkey = (gpos if order_by is None
+                            else np.asarray(order_by)[gpos])
+                    if kk < k:  # pad the chunk's candidate list to k columns
+                        padw = ((0, 0), (0, k - kk))
+                        val_c = np.pad(val_c[:q], padw,
+                                       constant_values=np.inf)
+                        gpos = np.pad(gpos, padw, constant_values=-1)
+                        gkey = np.pad(gkey, padw,
+                                      constant_values=KBEST_KEY_PAD)
+                    else:
+                        val_c = val_c[:q]
+                    best_v, best_key, best_pos = kbest_lex_merge(
+                        k, np.concatenate([best_v, val_c], axis=1),
+                        np.concatenate([best_key, gkey], axis=1),
+                        np.concatenate([best_pos, gpos], axis=1))
+                if ptr >= n_bands:
+                    break
+                kth = best_v[:, k - 1]
+                if init_kth is not None:
+                    kth = np.minimum(kth, init_kth)
+                bound = factor * gap[:, visit[ptr:]]
+                if np.all(bound >= kth[:, None] + PRUNE_MARGIN):
+                    if stats_out is not None:
+                        stats_out["early_stop"] = True
+                    break
+                if deadline is not None and deadline.expired:
+                    # budget exhausted before the certificate closed: stop
+                    # here and report the residual gap — the distance the
+                    # kth bound would have to move for the partial answer
+                    # to be provably exact (inf when fewer than k candidates
+                    # were even seen)
+                    if stats_out is not None:
+                        stats_out["partial"] = True
+                        stats_out["cert_gap"] = float(np.max(np.maximum(
+                            kth[:, None] + PRUNE_MARGIN - bound, 0.0)))
+                    break
+        if stats_out is not None:
+            stats_out["bands_visited"] = ptr
+            stats_out["rows_visited"] = visited_rows
+            stats_out["rounds"] = rounds
+        return best_pos, best_v
 
 
 # ---------------------------------------------------------------------------

@@ -65,15 +65,17 @@ class BandedLayout:
     def __init__(self, store: SketchStore, metric: str,
                  band_rows: int = 1024, registry=None,
                  slots: np.ndarray | None = None, device=None):
-        # banding effectiveness counters: visited vs pruned per query, and
-        # how often the exactness certificate stopped the scan early.  The
-        # instruments are cached here once — under NULL_REGISTRY they are
-        # shared no-ops and the stats_out dict is never even built.
+        # banding effectiveness counters: visited vs pruned per query, the
+        # walk's rounds, and how often the exactness certificate stopped
+        # the scan early.  The instruments are cached here once — under
+        # NULL_REGISTRY they are shared no-ops and the stats_out dict is
+        # never even built.
         reg = NULL_REGISTRY if registry is None else registry
         self._obs_off = reg.is_null
         self._c_queries = reg.counter("index_banded_queries_total")
         self._c_visited = reg.counter("index_bands_visited_total")
         self._c_pruned = reg.counter("index_bands_pruned_total")
+        self._c_rounds = reg.counter("index_walk_rounds_total")
         self._c_early = reg.counter("index_band_early_stops_total")
         self.metric = metric
         self.d = store.d
@@ -180,6 +182,7 @@ class BandedLayout:
             self._c_queries.inc()
             self._c_visited.inc(st["bands_visited"])
             self._c_pruned.inc(st["n_bands"] - st["bands_visited"])
+            self._c_rounds.inc(st["rounds"])
             if st["early_stop"]:
                 self._c_early.inc()
         if info_out is not None and st is not None:

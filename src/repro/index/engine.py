@@ -335,40 +335,41 @@ class QueryEngine:
         spec's params through exactly this path, which is what makes a
         completed migration bit-identical to a fresh build.
         """
-        if params is None:
-            params = self.params
-        w = params.packed_width
-        if isinstance(queries, (tuple, list)):
-            idx_host, val_host = queries
-            # validate on host BEFORE the device transfer: no sync on the
-            # serving path when (as usual) the input is already numpy
-            idx_host = np.asarray(idx_host)
-            if idx_host.shape != np.shape(val_host) or idx_host.ndim != 2:
-                raise ValueError("COO input needs matching (k, m) "
-                                 "indices/values")
-            if idx_host.size and (idx_host.max() >= params.n_dims
-                                  or idx_host.min() < 0):
+        with obs.span("engine.sketch"):
+            if params is None:
+                params = self.params
+            w = params.packed_width
+            if isinstance(queries, (tuple, list)):
+                idx_host, val_host = queries
+                # validate on host BEFORE the device transfer: no sync on the
+                # serving path when (as usual) the input is already numpy
+                idx_host = np.asarray(idx_host)
+                if idx_host.shape != np.shape(val_host) or idx_host.ndim != 2:
+                    raise ValueError("COO input needs matching (k, m) "
+                                     "indices/values")
+                if idx_host.size and (idx_host.max() >= params.n_dims
+                                      or idx_host.min() < 0):
+                    raise ValueError(
+                        f"COO indices out of range [0, {params.n_dims})")
+                indices = jnp.asarray(idx_host, jnp.int32)
+                values = jnp.asarray(val_host, jnp.int32)
+                k = indices.shape[0]
+                if k == 0:
+                    return jnp.zeros((0, w), jnp.int32), 0
+                mpad = pow2_bucket(indices.shape[1])
+                wpad = ((0, pow2_bucket(k) - k), (0, mpad - indices.shape[1]))
+                sk = sketch_sparse_jit(params, jnp.pad(indices, wpad),
+                                       jnp.pad(values, wpad))
+                return sk, k
+            x = jnp.asarray(queries, jnp.int32)
+            if x.ndim != 2 or x.shape[1] != params.n_dims:
                 raise ValueError(
-                    f"COO indices out of range [0, {params.n_dims})")
-            indices = jnp.asarray(idx_host, jnp.int32)
-            values = jnp.asarray(val_host, jnp.int32)
-            k = indices.shape[0]
+                    f"expected dense (k, {params.n_dims}) rows, "
+                    f"got {x.shape}")
+            k = x.shape[0]
             if k == 0:
                 return jnp.zeros((0, w), jnp.int32), 0
-            mpad = pow2_bucket(indices.shape[1])
-            wpad = ((0, pow2_bucket(k) - k), (0, mpad - indices.shape[1]))
-            sk = sketch_sparse_jit(params, jnp.pad(indices, wpad),
-                                   jnp.pad(values, wpad))
-            return sk, k
-        x = jnp.asarray(queries, jnp.int32)
-        if x.ndim != 2 or x.shape[1] != params.n_dims:
-            raise ValueError(
-                f"expected dense (k, {params.n_dims}) rows, "
-                f"got {x.shape}")
-        k = x.shape[0]
-        if k == 0:
-            return jnp.zeros((0, w), jnp.int32), 0
-        return sketch_dense_jit(params, pad_rows_pow2(x)), k
+            return sketch_dense_jit(params, pad_rows_pow2(x)), k
 
     # -- ingestion ----------------------------------------------------------
 
@@ -395,16 +396,17 @@ class QueryEngine:
 
     def add_sparse(self, indices, values) -> np.ndarray:
         """Ingest padded-COO categorical rows; returns ids (k,)."""
-        self._drive()
-        store, params = self._ingest_target()
-        sk, k = self._sketch((indices, values), params=params)
-        ids = store.add(sk, n_valid=k)
-        if k:
-            if self.raw is not None:
-                self.raw.put(ids, indices, values)
-            self._track_drift(
-                np.count_nonzero(np.asarray(values), axis=1))
-        return ids
+        with obs.span("engine.add_sparse", rows=len(indices)):
+            self._drive()
+            store, params = self._ingest_target()
+            sk, k = self._sketch((indices, values), params=params)
+            ids = store.add(sk, n_valid=k)
+            if k:
+                if self.raw is not None:
+                    self.raw.put(ids, indices, values)
+                self._track_drift(
+                    np.count_nonzero(np.asarray(values), axis=1))
+            return ids
 
     def add_packed(self, packed, raw=None,
                    spec: SketchSpec | None = None) -> np.ndarray:
@@ -435,25 +437,26 @@ class QueryEngine:
         return ids
 
     def remove(self, ids) -> int:
-        self._drive()
         ids = np.atleast_1d(np.asarray(ids, np.int64))
-        if self._mig is None:
-            n = self.store.remove(ids)
-        else:
-            if len(np.unique(ids)) != len(ids):
-                raise ValueError("duplicate ids in remove batch")
-            # validate membership BEFORE mutating any store, so a bad id
-            # cannot leave a partial cross-store remove behind
-            groups: dict[int, tuple[SketchStore, list[int]]] = {}
-            for id_ in ids.tolist():
-                store = self._mig.store_of(id_)  # KeyError on unknown
-                groups.setdefault(id(store), (store, []))[1].append(id_)
-            for store, grp in groups.values():
-                store.remove(np.asarray(grp, np.int64))
-            n = len(ids)
-        if self.raw is not None:
-            self.raw.drop(ids)
-        return n
+        with obs.span("engine.remove", rows=len(ids)):
+            self._drive()
+            if self._mig is None:
+                n = self.store.remove(ids)
+            else:
+                if len(np.unique(ids)) != len(ids):
+                    raise ValueError("duplicate ids in remove batch")
+                # validate membership BEFORE mutating any store, so a bad
+                # id cannot leave a partial cross-store remove behind
+                groups: dict[int, tuple[SketchStore, list[int]]] = {}
+                for id_ in ids.tolist():
+                    store = self._mig.store_of(id_)  # KeyError on unknown
+                    groups.setdefault(id(store), (store, []))[1].append(id_)
+                for store, grp in groups.values():
+                    store.remove(np.asarray(grp, np.int64))
+                n = len(ids)
+            if self.raw is not None:
+                self.raw.drop(ids)
+            return n
 
     def compact(self) -> None:
         self._drive()
@@ -618,14 +621,15 @@ class QueryEngine:
         to that dim rounded up to a multiple of 128, the width the Cabin
         kernels take (the theory's d is a minimum, so rounding up keeps
         its bound).  No-op unless auto_migrate."""
-        self._nnz_window.extend(int(c) for c in nnz_counts)
-        if not self.auto_migrate or self._mig is not None:
-            return
-        if len(self._nnz_window) < min(64, self.drift_window):
-            return  # too few observations to call a drift
-        p = max(1, int(np.ceil(np.percentile(
-            np.fromiter(self._nnz_window, np.int64), self.drift_pct))))
-        need = theory.sketch_dim(p, self.drift_delta)
+        with obs.span("engine.track_drift", rows=len(nnz_counts)):
+            self._nnz_window.extend(int(c) for c in nnz_counts)
+            if not self.auto_migrate or self._mig is not None:
+                return
+            if len(self._nnz_window) < min(64, self.drift_window):
+                return  # too few observations to call a drift
+            p = max(1, int(np.ceil(np.percentile(
+                np.fromiter(self._nnz_window, np.int64), self.drift_pct))))
+            need = theory.sketch_dim(p, self.drift_delta)
         if need > self.d:
             self.migrate(d=-(-need // 128) * 128, drive="lazy")
 
@@ -737,7 +741,7 @@ class QueryEngine:
         kk = min(k, len(self.store))
         if q == 0 or kk == 0:
             return (np.zeros((q, 0), np.int64), np.zeros((q, 0), np.float32))
-        q_host = np.asarray(sk[:q])  # needed for band planning regardless
+        q_host, q_weights = self._query_host(sk, q)
         key = None  # caching disabled: skip the device sync for the key
         if self._cache_entries:
             key = ("topk", kk, self.store.version, q_host.tobytes())
@@ -748,7 +752,6 @@ class QueryEngine:
                 # cache is a free upgrade to the full answer
                 return hit[0].copy(), hit[1].copy()
         layout = self._layout()
-        q_weights = packing.np_popcount_rows(q_host)
         out = layout.topk(pad_rows_pow2(sk), q_weights, kk, q_valid=q,
                           block=self.block, mode=self.mode,
                           deadline=deadline, info_out=info_out)
@@ -756,6 +759,15 @@ class QueryEngine:
             key = None  # a partial answer must not shadow the exact one
         self._remember(key, out)
         return out
+
+    @staticmethod
+    def _query_host(sk, q: int) -> tuple[np.ndarray, np.ndarray]:
+        """(host copy of the q real query sketches, their popcounts): the
+        band planner's input and the result cache's key, and the query
+        path's device sync before the walk."""
+        with obs.span("engine.query_sync", rows=q):
+            q_host = np.asarray(sk[:q])
+            return q_host, packing.np_popcount_rows(q_host)
 
     def radius(self, queries, r: float) -> list[np.ndarray]:
         """All stored rows within distance < r of each query: a list of Q
@@ -796,7 +808,7 @@ class QueryEngine:
             return []
         if r <= 0:  # dist >= 0 and the test is strict: provably no hits
             return [np.zeros(0, np.int64) for _ in range(q)]
-        q_host = np.asarray(sk[:q])  # needed for band planning regardless
+        q_host, q_weights = self._query_host(sk, q)
         key = None
         if self._cache_entries:
             key = ("radius", float(r), self.store.version, q_host.tobytes())
@@ -806,7 +818,6 @@ class QueryEngine:
         hits: list[list[np.ndarray]] = [[] for _ in range(q)]
         if len(self.store):
             layout = self._layout()
-            q_weights = packing.np_popcount_rows(q_host)
             # partition memberships partition the alive set: per-partition
             # hits union to exactly the batch engine's answer on the full
             # membership (partition.radius_hits — the one collection pass)
@@ -855,9 +866,8 @@ class QueryEngine:
         staged = []
         for layout, spec in tiers:
             sk, _ = sketched[spec.version]
-            q_host = np.asarray(sk[:q])
             staged.append((layout, pad_rows_pow2(sk),
-                           packing.np_popcount_rows(q_host)))
+                           self._query_host(sk, q)[1]))
         return partition.topk_across_tiers(kk, staged, q_valid=q,
                                            block=self.block, mode=self.mode)
 
@@ -878,9 +888,8 @@ class QueryEngine:
         hits: list[list[np.ndarray]] = [[] for _ in range(q)]
         for layout, spec in tiers:
             sk, _ = sketched[spec.version]
-            q_host = np.asarray(sk[:q])
             partition.radius_hits(
-                layout, pad_rows_pow2(sk), packing.np_popcount_rows(q_host),
+                layout, pad_rows_pow2(sk), self._query_host(sk, q)[1],
                 q, r, metric=self.metric, block=min(self.block, 256),
                 mode=self.mode, hits=hits)
         return [np.sort(np.concatenate(h)) if h else np.zeros(0, np.int64)

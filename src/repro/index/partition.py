@@ -542,7 +542,7 @@ class PartitionSet:
         partial, cert_gap = False, 0.0
         bands_visited = rows_visited = 0
         want_info = info_out is not None or deadline is not None
-        with obs.span("partition.merge", shards=self.n_shards, k=kk,
+        with obs.span("partition.topk", shards=self.n_shards, k=kk,
                       role=self.role):
             for g in self._groups:
                 if g.base.banded.n_alive:
@@ -592,20 +592,22 @@ class PartitionSet:
         `threshold_pairs` hits union to exactly the batch engine's answer
         on the full membership."""
         out = []
-        for g in self._groups:
-            bl = g.base.banded
-            if bl.n_alive:
-                mask = bl.candidate_bands(query_weights, radius)
-                if not self.registry.is_null:
-                    kept = int(np.count_nonzero(mask))
-                    bl._c_queries.inc()
-                    bl._c_visited.inc(kept)
-                    bl._c_pruned.inc(bl.n_bands - kept)
-                sel, n_sel, sel_ids = bl.select(mask)
-                if n_sel:
-                    out.append((sel, n_sel, sel_ids))
-            if g.delta.n_rows:
-                out.append((g.delta.matrix, g.delta.n_rows, g.delta.ids))
+        with obs.span("partition.radius_tiers", shards=self.n_shards):
+            for g in self._groups:
+                bl = g.base.banded
+                if bl.n_alive:
+                    mask = bl.candidate_bands(query_weights, radius)
+                    if not self.registry.is_null:
+                        kept = int(np.count_nonzero(mask))
+                        bl._c_queries.inc()
+                        bl._c_visited.inc(kept)
+                        bl._c_pruned.inc(bl.n_bands - kept)
+                    sel, n_sel, sel_ids = bl.select(mask)
+                    if n_sel:
+                        out.append((sel, n_sel, sel_ids))
+                if g.delta.n_rows:
+                    out.append((g.delta.matrix, g.delta.n_rows,
+                                g.delta.ids))
         return out
 
 
@@ -656,12 +658,13 @@ def radius_hits(layout, queries_padded: jnp.ndarray,
         pairs = allpairs.threshold_pairs(
             queries_padded, sel, d=layout.d, threshold=r, metric=metric,
             block=block, mode=mode, n_valid=q, m_valid=n_sel)
-        by_q = pairs[np.argsort(pairs[:, 0], kind="stable")]
-        splits = np.searchsorted(by_q[:, 0], np.arange(q + 1))
-        for qi in range(q):
-            seg = sel_ids[by_q[splits[qi]: splits[qi + 1], 1]]
-            if seg.size:
-                hits[qi].append(seg)
+        with obs.span("partition.radius_group", pairs=len(pairs)):
+            by_q = pairs[np.argsort(pairs[:, 0], kind="stable")]
+            splits = np.searchsorted(by_q[:, 0], np.arange(q + 1))
+            for qi in range(q):
+                seg = sel_ids[by_q[splits[qi]: splits[qi + 1], 1]]
+                if seg.size:
+                    hits[qi].append(seg)
 
 
 def snapshot_subtrees(store: SketchStore, raw=None, migration=None) -> dict:

@@ -411,35 +411,36 @@ class SketchStore:
 
     def _append(self, packed: jnp.ndarray, k: int, new_ids: np.ndarray,
                 *, notify: bool) -> np.ndarray:
-        kpad = pow2_bucket(k)
-        if packed.shape[0] < kpad:
-            packed = jnp.pad(packed, ((0, kpad - packed.shape[0]), (0, 0)))
-        elif packed.shape[0] > kpad:
-            packed = packed[:kpad]
-        if self._size + kpad > self.capacity:
-            self._grow_to(pow2_bucket(self._size + kpad))
-        self._sk_buf, self._wt_buf = _append_rows()(
-            self._sk_buf, self._wt_buf, packed, jnp.int32(self._size))
-        if self._placement is not None:
-            self._sk_buf = self._place(self._sk_buf)
-            self._wt_buf = self._place(self._wt_buf)
-        sl = slice(self._size, self._size + k)
-        self._ids[sl] = new_ids
-        self._alive[sl] = True
-        # host weight mirror reads back the device popcounts just written by
-        # _append_rows — k ints, cheaper than re-deriving from the packed
-        # batch on host
-        self._weights[sl] = np.asarray(self._wt_buf[sl], np.int64)
-        self._size += k
-        self._n_alive += k
-        self._next_id = max(self._next_id, int(new_ids[-1]) + 1)
-        self._c_added.inc(k)
-        self._bump()
-        if notify:
-            self._notify("add", new_ids,
-                         np.arange(self._size - k, self._size,
-                                   dtype=np.int64))
-        return new_ids
+        with obs.span("store.add", rows=k):
+            kpad = pow2_bucket(k)
+            if packed.shape[0] < kpad:
+                packed = jnp.pad(packed, ((0, kpad - packed.shape[0]), (0, 0)))
+            elif packed.shape[0] > kpad:
+                packed = packed[:kpad]
+            if self._size + kpad > self.capacity:
+                self._grow_to(pow2_bucket(self._size + kpad))
+            self._sk_buf, self._wt_buf = _append_rows()(
+                self._sk_buf, self._wt_buf, packed, jnp.int32(self._size))
+            if self._placement is not None:
+                self._sk_buf = self._place(self._sk_buf)
+                self._wt_buf = self._place(self._wt_buf)
+            sl = slice(self._size, self._size + k)
+            self._ids[sl] = new_ids
+            self._alive[sl] = True
+            # host weight mirror reads back the device popcounts just
+            # written by _append_rows — k ints, cheaper than re-deriving
+            # from the packed batch on host
+            self._weights[sl] = np.asarray(self._wt_buf[sl], np.int64)
+            self._size += k
+            self._n_alive += k
+            self._next_id = max(self._next_id, int(new_ids[-1]) + 1)
+            self._c_added.inc(k)
+            self._bump()
+            if notify:
+                self._notify("add", new_ids,
+                             np.arange(self._size - k, self._size,
+                                       dtype=np.int64))
+            return new_ids
 
     def remove(self, ids, *, notify: bool = True) -> int:
         """Tombstone rows by id (device buffers untouched).  Raises KeyError
@@ -450,6 +451,10 @@ class SketchStore:
         is unchanged globally, so per-id sidecars must not see a "remove" —
         but version/removed_count still bump so layouts resync."""
         ids = np.atleast_1d(np.asarray(ids, np.int64))
+        with obs.span("store.remove", rows=len(ids)):
+            return self._remove(ids, notify)
+
+    def _remove(self, ids: np.ndarray, notify: bool) -> int:
         if len(np.unique(ids)) != len(ids):
             raise ValueError("duplicate ids in remove batch")
         slots = np.searchsorted(self._ids[: self._size], ids)

@@ -1,14 +1,23 @@
 """repro.obs: the engine-wide flight recorder (DESIGN.md section 11).
 
-Three pieces, all mergeable and all removable:
+Four pieces, all removable:
 
   * `MetricsRegistry` (obs/registry.py) — counters, gauges, pow2-bucketed
     latency histograms with p50/p95/p99 extraction; registries merge
     (per-bucket integer addition — the same discipline that makes the
     sketches shard-friendly).
-  * `span` / `instant` tracing (obs/trace.py) — Chrome trace-event JSON
-    via `export_trace(path)`, loadable in Perfetto; runtime.faultinject
-    crash-point crossings appear as instant events.
+  * `span` / `instant` tracing — `span` IS `jax.profiler.TraceAnnotation`
+    and `instant` a zero-length one, so a profiler capture
+    (`jax.profiler.trace(dir)`, or a client of `start_server`) holds the
+    program's spans beside the device ops, on one clock, with keyword
+    args as event stats; outside a session a span costs about a
+    microsecond and records nothing.  Names are `<module>.<what>` and
+    args ints or strings: readers key on them (bench/spans.py).
+    runtime.faultinject crash-point crossings appear as instants.
+  * compile accounting — one `jax.monitoring` listener counts JAX's
+    compile phases into the process-default registry
+    (`jax_compiles_total{phase}`, `jax_compile_seconds_total{phase}`) and
+    marks each with an `obs.compile` instant carrying its duration.
   * exporters — `snapshot()`, `render_prom()` (Prometheus text format),
     and the `QueryEngine.stats()` facade built on them.
 
@@ -27,21 +36,28 @@ from __future__ import annotations
 
 import os
 
-from repro.obs import trace as _trace_mod
+import jax
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from repro.obs.registry import (Counter, Gauge, Histogram,  # noqa: F401
                                 MetricsRegistry, NULL_REGISTRY,
                                 NullRegistry)
-from repro.obs.trace import (TRACE_CAPACITY, clear_trace,  # noqa: F401
-                             export_trace, trace_events)
 from repro.runtime import faultinject as _faultinject
 
 __all__ = [
     "MetricsRegistry", "NullRegistry", "NULL_REGISTRY",
     "Counter", "Gauge", "Histogram",
-    "span", "instant", "export_trace", "clear_trace", "trace_events",
-    "enabled", "configure", "new_registry", "get_registry", "render_prom",
-    "snapshot", "TRACE_CAPACITY",
+    "span", "instant", "enabled", "configure", "new_registry",
+    "get_registry", "render_prom", "snapshot", "COMPILE_EVENTS",
 ]
+
+# JAX's compile phases, as jax.monitoring names them: tracing to a jaxpr,
+# lowering to MLIR, and the backend compile (or persistent-cache load)
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
 
 
 class _NullSpan:
@@ -65,6 +81,12 @@ def _noop_instant(name, **args):
     return None
 
 
+def _instant(name: str, **args) -> None:
+    """A zero-length span at this point of the calling thread."""
+    with _TraceAnnotation(name, **args):
+        pass
+
+
 _enabled = os.environ.get("REPRO_OBS", "1").strip().lower() not in (
     "0", "false", "off")
 _default_registry: MetricsRegistry | None = None
@@ -85,8 +107,8 @@ def configure(on: bool) -> None:
     global _enabled, span, instant
     _enabled = bool(on)
     if _enabled:
-        span = _trace_mod.span
-        instant = _trace_mod.instant
+        span = _TraceAnnotation
+        instant = _instant
         _faultinject.set_observer(_crash_point_instant)
     else:
         span = _noop_span
@@ -97,8 +119,22 @@ def configure(on: bool) -> None:
 def _crash_point_instant(point: str) -> None:
     """faultinject observer: each crash-point crossing becomes an instant
     event, so durability boundaries are visible inside migration/save
-    spans in the exported trace."""
-    _trace_mod.instant("crash_point", point=point)
+    spans in a profiler capture."""
+    _instant("crash_point", point=point)
+
+
+def _on_compile_event(event: str, duration: float, **_) -> None:
+    """jax.monitoring listener: count each compile phase into the
+    process-default registry and mark its end with an `obs.compile`
+    instant whose `us` arg is its duration, so a profile shows where the
+    compile stalls fell.  Runs on the compiling thread."""
+    phase = COMPILE_EVENTS.get(event)
+    if phase is None or not _enabled:
+        return
+    reg = get_registry()
+    reg.counter("jax_compiles_total", phase=phase).inc()
+    reg.counter("jax_compile_seconds_total", phase=phase).inc(duration)
+    _instant("obs.compile", phase=phase, us=int(duration * 1e6))
 
 
 def new_registry() -> MetricsRegistry | NullRegistry:
@@ -134,3 +170,6 @@ def snapshot(registry=None) -> dict:
 
 
 configure(_enabled)
+# jax.monitoring has no unregister: the listener is installed once and
+# checks the switch itself
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)

@@ -118,7 +118,8 @@ class Request:
     """
 
     __slots__ = ("op", "cls", "queries", "fmt", "rows", "param", "deadline",
-                 "t_submit", "t_flush", "_event", "_result", "_lock")
+                 "t_submit", "t_flush", "flush", "_event", "_result",
+                 "_lock")
 
     def __init__(self, op, cls, queries, fmt, rows, param, deadline):
         self.op = op
@@ -130,6 +131,7 @@ class Request:
         self.deadline = deadline
         self.t_submit = time.monotonic()
         self.t_flush = None
+        self.flush = None  # the door's sequence number of its flush
         self._event = threading.Event()
         self._result: ServeResult | None = None
         self._lock = threading.Lock()
@@ -175,6 +177,7 @@ class _Group:
 
     key: tuple
     members: list = field(default_factory=list)
+    seq: int = 0  # the door's flush number: the `flush` arg of its spans
 
     @property
     def rows(self) -> int:
@@ -225,6 +228,7 @@ class FrontDoor:
         self.answered = 0
         self.double_answers = 0
         self._n_lock = threading.Lock()
+        self._n_flushes = 0  # touched by the dispatcher thread alone
 
         reg = self.obs
         self._c_answered = {c: reg.counter("frontdoor_answered_total", cls=c)
@@ -362,7 +366,8 @@ class FrontDoor:
             members = self.queue.take_group(self.max_batch_rows)
             if members is None:
                 return  # closed and drained
-            group = _Group(members[0].key, members)
+            self._n_flushes += 1
+            group = _Group(members[0].key, members, seq=self._n_flushes)
             try:
                 self._fill_window(group)
                 self._flush(group)
@@ -396,14 +401,15 @@ class FrontDoor:
     def _fill_window(self, group: _Group) -> None:
         """Hold a non-full group briefly so arrivals can coalesce —
         bounded by batch-fill, max_wait, and member deadlines."""
-        while group.rows < self.max_batch_rows:
-            now = time.monotonic()
-            due = self._flush_due(group, now)
-            if now >= due:
-                return
-            self.queue.wait_for_arrival(min(due - now, 0.005))
-            self.queue.collect_matching(group.members, group.key,
-                                        self.max_batch_rows)
+        with obs.span("frontdoor.fill", flush=group.seq):
+            while group.rows < self.max_batch_rows:
+                now = time.monotonic()
+                due = self._flush_due(group, now)
+                if now >= due:
+                    return
+                self.queue.wait_for_arrival(min(due - now, 0.005))
+                self.queue.collect_matching(group.members, group.key,
+                                            self.max_batch_rows)
 
     def _flush(self, group: _Group) -> None:
         """Partition by deadline pressure, run, publish.
@@ -416,6 +422,7 @@ class FrontDoor:
         t_flush = time.monotonic()
         for m in group.members:
             m.t_flush = t_flush
+            m.flush = group.seq
             self._h_wait.observe((t_flush - m.t_submit) * 1e3)
         self._c_flushes.inc()
         self._h_rows.observe(group.rows)
@@ -431,7 +438,7 @@ class FrontDoor:
                        else (0.0 if d.expired else est_s + 1.0))
                 (exact if rem > est_s else budgeted).append(m)
         if exact:
-            self._run_members(group.key, exact, deadline=None)
+            self._run_members(group, exact, deadline=None)
         if budgeted:
             if op == "radius":
                 # radius has no budgeted walk: run members still inside
@@ -443,10 +450,10 @@ class FrontDoor:
                         self._publish(m, self._empty_result(
                             m, partial=True, timed_out=True))
                 if live:
-                    self._run_members(group.key, live, deadline=None)
+                    self._run_members(group, live, deadline=None)
             else:
                 tightest = min(budgeted, key=self._remaining).deadline
-                self._run_members(group.key, budgeted, deadline=tightest)
+                self._run_members(group, budgeted, deadline=tightest)
 
     @staticmethod
     def _remaining(m: Request) -> float:
@@ -454,17 +461,17 @@ class FrontDoor:
         return (d.remaining_s() if hasattr(d, "remaining_s")
                 else (0.0 if d.expired else float("inf")))
 
-    def _run_members(self, key, members: list, deadline) -> None:
-        """One engine call for `members`, with crash points, bounded
-        retry, and exactly-once publication."""
-        op, param, fmt = key
+    def _run_members(self, group: _Group, members: list, deadline) -> None:
+        """One engine call for `members` (of `group`), with crash points,
+        bounded retry, and exactly-once publication."""
+        op, param, fmt = group.key
         queries = self._concat([m.queries for m in members], fmt)
         attempt = 0
         out = None
         err: BaseException | None = None
         while True:
             try:
-                with obs.span("frontdoor.flush", op=op,
+                with obs.span("frontdoor.flush", op=op, flush=group.seq,
                               rows=sum(m.rows for m in members)):
                     faultinject.crash_point(_CP_FLUSH)
                     t0 = time.perf_counter()
@@ -491,7 +498,8 @@ class FrontDoor:
             return
         self.estimator.observe("topk" if op == "assign" else op, service_ms)
         self._h_service["topk" if op == "assign" else op].observe(service_ms)
-        self._distribute(op, members, out)
+        with obs.span("frontdoor.distribute", flush=group.seq):
+            self._distribute(op, members, out)
 
     def _concat(self, parts: list, fmt: str):
         if len(parts) == 1:

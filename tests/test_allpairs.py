@@ -259,3 +259,18 @@ def test_kmode_precomputed_engine_equals_oracle():
         engine = kmode_precomputed(None, sk.copy(), k=4, seed=seed,
                                    sketch_dim=D)
         np.testing.assert_array_equal(legacy, engine)
+
+
+def test_threshold_scan_lowering_names_its_scopes():
+    """The radius tile loop's device ops carry stable scope names in the
+    HLO metadata, which a profile's `tf_op` stat reports: the loop, the
+    distance tile, and the hit extraction."""
+    a = jnp.zeros((256, 4), jnp.int32)
+    text = allpairs._threshold_pairs_impl.lower(
+        a, a, jnp.zeros((1, 2), jnp.int32), jnp.float32(30.0),
+        jnp.int32(256), jnp.int32(256), block=256, capacity=4096,
+        symmetric=False, metric="cham", mode="popcount", d=128,
+    ).as_text(debug_info=True)
+    for scope in ("allpairs.threshold_scan", "allpairs.tile_dist",
+                  "allpairs.append_hits"):
+        assert scope in text, scope

@@ -403,3 +403,34 @@ def test_retries_exhausted_surface_as_error_result(engine):
         assert fd.double_answers == 0
     finally:
         fd.close()
+
+
+def test_flush_spans_share_the_flush_number(engine):
+    """One flush's fill, flush and distribute spans carry one `flush`
+    number, which the requests it served keep; its engine call nests in
+    its `frontdoor.flush` span."""
+    from repro import obs
+    from _capture import capture
+
+    if not obs.enabled():
+        pytest.skip("obs disabled in this environment")
+    qs = [_rows(2, 300 + i) for i in range(2)]
+    for q in qs:
+        engine.topk(q, 3)  # compile outside the capture
+    with capture() as cap:
+        with FrontDoor(engine, max_wait_ms=1.0) as fd:
+            reqs = []
+            for q in qs:  # one at a time: two flushes
+                reqs.append(fd.submit("topk", q, k=3))
+                assert reqs[-1].result(timeout=60).ok
+    assert [r.flush for r in reqs] == [1, 2]
+    for n in (1, 2):
+        [fill, flush, dist] = [
+            [e for e in cap.named(f"frontdoor.{what}")
+             if e.args["flush"] == n]
+            for what in ("fill", "flush", "distribute")]
+        assert len(fill) == len(flush) == len(dist) == 1
+        assert fill[0].end_ns <= flush[0].start_ns
+        assert flush[0].end_ns <= dist[0].start_ns
+        assert flush[0].args["op"] == "topk" and flush[0].args["rows"] == 2
+        assert any(e.within(flush[0]) for e in cap.named("engine.topk"))

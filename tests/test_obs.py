@@ -6,18 +6,19 @@ Four contracts under test:
     bucket of the true order statistic, merge is lossless at the bucket
     level, counters stay exact (they mirror the engine's own accounting);
   * exporters — `render_prom()` is valid Prometheus text exposition
-    (cumulative monotone buckets, `_count`/`_sum` agreement) and
-    `export_trace()` is loadable Chrome trace-event JSON whose spans cover
-    the serving ops and whose instants mark faultinject crash points;
+    (cumulative monotone buckets, `_count`/`_sum` agreement), and a
+    profiler capture holds spans that cover the serving ops, nest by
+    layer, share the device ops' clock, and instants that mark
+    faultinject crash points and JAX's compiles;
   * the off switch — REPRO_OBS=0 (env, subprocess-tested) and
     `obs.configure(False)` (runtime) hand every call site shared null
-    instruments: results stay bit-identical and ZERO additional jit graphs
-    compile relative to the instrumented run;
+    instruments and put no span in a capture: results stay bit-identical
+    and ZERO additional jit graphs compile relative to the instrumented
+    run;
   * gauge truth at recovery — `engine_migration_progress` is exact at
     every faultinject crash/resume point of the migration matrix.
 """
 
-import json
 import math
 import os
 import subprocess
@@ -33,12 +34,24 @@ from repro.index.engine import compile_cache_entries
 from repro.obs.registry import Histogram, MetricsRegistry
 from repro.runtime import faultinject
 
+from _capture import capture
+
 N_DIMS = 300
 P = CabinParams(n_dims=N_DIMS, sketch_dim=64, psi_seed=21, pi_seed=22)
 P_NEW = CabinParams(n_dims=N_DIMS, sketch_dim=128, psi_seed=21, pi_seed=22)
 
 requires_obs = pytest.mark.skipif(
     not obs.enabled(), reason="suite running with REPRO_OBS=0")
+
+# the modules whose spans are the program's (span names are
+# `<module>.<what>`; crash points are `crash_point` instants)
+PROGRAM_SPANS = ("engine.", "allpairs.", "partition.", "store.",
+                 "frontdoor.", "migrate.", "merge_tree.", "ingest.",
+                 "cluster.", "obs.", "crash_point")
+
+
+def _program_spans(cap) -> set:
+    return {n for n in cap.names() if n.startswith(PROGRAM_SPANS)}
 
 
 def _rows(n, seed):
@@ -213,13 +226,14 @@ def test_disabled_path_bit_identical_and_zero_new_graphs(obs_restore):
 
 def test_repro_obs_env_kills_the_layer_in_subprocess():
     """The deployment switch: REPRO_OBS=0 read at import time."""
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
+    here = os.path.dirname(os.path.abspath(__file__))
+    src = os.path.join(os.path.dirname(here), "src")
     child = (
         "import numpy as np\n"
         "from repro import obs\n"
         "from repro.core.cabin import CabinParams\n"
         "from repro.index import QueryEngine\n"
+        "from _capture import capture\n"
         "assert not obs.enabled()\n"
         "assert obs.new_registry() is obs.NULL_REGISTRY\n"
         "p = CabinParams(n_dims=64, sketch_dim=32, psi_seed=1, pi_seed=2)\n"
@@ -227,13 +241,18 @@ def test_repro_obs_env_kills_the_layer_in_subprocess():
         "assert eng.obs.is_null\n"
         "x = np.zeros((4, 64), np.int32)\n"
         "x[:, :5] = 1 + np.arange(5)\n"
-        "eng.add_dense(x)\n"
-        "eng.topk(x, 2)\n"
+        "with capture() as cap:\n"
+        "    eng.add_dense(x)\n"
+        "    eng.topk(x, 2)\n"
         "assert eng.obs.snapshot() == {}\n"
         "assert 'latency_ms' not in eng.stats()\n"
-        "assert obs.trace_events() == []\n"
+        f"prefixes = {PROGRAM_SPANS!r}\n"
+        "assert cap.names(), 'the capture recorded nothing'\n"
+        "assert not [n for n in cap.names() if n.startswith(prefixes)]\n"
+        "assert obs.get_registry() is obs.NULL_REGISTRY\n"
         "print('NULLED')\n")
-    env = dict(os.environ, PYTHONPATH=src, REPRO_OBS="0")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join([src, here]),
+               REPRO_OBS="0")
     proc = subprocess.run([sys.executable, "-c", child], env=env,
                           capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr
@@ -246,31 +265,31 @@ def test_repro_obs_env_kills_the_layer_in_subprocess():
 
 
 @requires_obs
-def test_flight_recorder_acceptance(tmp_path):
+def test_flight_recorder_acceptance():
     """One mixed serving journey (adds, removes, queries, a full spec
-    migration) exports a loadable Chrome trace whose spans cover every op
-    and whose instants mark the crash points crossed, plus a Prometheus
-    snapshot whose latency quantiles agree with independently measured
-    wall times to within one pow2 bucket."""
+    migration) under a profiler capture: its spans cover every op and its
+    instants mark the crash points crossed, plus a Prometheus snapshot
+    whose latency quantiles agree with independently measured wall times
+    to within one pow2 bucket."""
     import time
 
-    obs.clear_trace()
-    eng = QueryEngine(P, cache_entries=0, keep_raw=True)
-    eng.add_dense(X[:40])
-    eng.remove(np.arange(3))
-    eng.add_dense(X[40:])
+    with capture() as cap:
+        eng = QueryEngine(P, cache_entries=0, keep_raw=True)
+        eng.add_dense(X[:40])
+        eng.remove(np.arange(3))
+        eng.add_dense(X[40:])
 
-    outer_ms = []
-    for _ in range(8):
-        t0 = time.perf_counter()
-        eng.topk(QUERIES, 5)
-        outer_ms.append((time.perf_counter() - t0) * 1e3)
-    eng.radius(QUERIES, 60.0)
-    eng.pairwise(QUERIES[:2], ids=eng.ids()[:10])
+        outer_ms = []
+        for _ in range(8):
+            t0 = time.perf_counter()
+            eng.topk(QUERIES, 5)
+            outer_ms.append((time.perf_counter() - t0) * 1e3)
+        eng.radius(QUERIES, 60.0)
+        eng.pairwise(QUERIES[:2], ids=eng.ids()[:10])
 
-    eng.migrate(new_params=P_NEW, batch_rows=16, drive="manual")
-    while eng.migration_step():
-        pass
+        eng.migrate(new_params=P_NEW, batch_rows=16, drive="manual")
+        while eng.migration_step():
+            pass
     assert not eng.migrating
 
     # -- counters/histograms tell the same story as the engine ------------
@@ -297,32 +316,136 @@ def test_flight_recorder_acceptance(tmp_path):
     assert 'engine_query_latency_ms_bucket{op="topk",le="+Inf"} 8' in text
     assert "engine_rows_alive" in text and "store_rows_added_total" in text
 
-    # -- the trace is loadable and structurally sound ----------------------
-    out = str(tmp_path / "trace.json")
-    n = obs.export_trace(out)
-    with open(out) as f:
-        doc = json.load(f)
-    evs = doc["traceEvents"]
-    assert len(evs) == n > 0
-    names = {e["name"] for e in evs}
+    # -- the capture holds the journey's spans and crash points -----------
+    names = cap.names()
     assert {"engine.topk", "engine.radius", "engine.pairwise",
-            "migrate.batch", "migrate.fold", "store.append",
-            "crash_point"} <= names or \
-        {"engine.topk", "engine.radius", "engine.pairwise",
-         "migrate.batch", "migrate.fold", "crash_point"} <= names
-    for e in evs:
-        assert e["ph"] in ("X", "i")
-        assert e["ts"] >= 0 and "pid" in e and "tid" in e
-        if e["ph"] == "X":
-            assert e["dur"] >= 0
-    crossed = {e["args"]["point"] for e in evs if e["name"] == "crash_point"}
+            "engine.sketch", "migrate.batch", "migrate.fold", "store.add",
+            "store.remove", "crash_point"} <= names
+    assert len(cap.named("engine.topk")) == 8
+    for e in cap.events:
+        assert e.end_ns >= e.start_ns >= 0
+    crossed = {e.args["point"] for e in cap.named("crash_point")}
     assert {"migrate.start", "migrate.batch.resketched",
             "migrate.batch.committed", "migrate.fold",
             "migrate.published"} <= crossed
-    # export is a read, clear is the reset
-    assert obs.trace_events()
-    obs.clear_trace()
-    assert obs.trace_events() == []
+    # every crash point falls inside the migration span that crossed it
+    batches = cap.named("migrate.batch")
+    for e in cap.named("crash_point"):
+        if e.args["point"].startswith("migrate.batch."):
+            assert any(e.within(b) for b in batches), e
+
+
+@requires_obs
+def test_walk_spans_nest_inside_engine_topk_and_layer_spans_exist():
+    """A top-k flush's band walk and its rounds nest inside `engine.topk`
+    (through `partition.topk`); radius and ingest record their own layer
+    spans."""
+    eng = QueryEngine(P, band_rows=8, cache_entries=0)
+    eng.add_dense(X)
+    eng.topk(QUERIES, 5)  # compile outside the capture
+    coo = (np.tile(np.arange(10), (4, 1)), np.ones((4, 10), np.int32))
+    with capture() as cap:
+        eng.topk(QUERIES, 5)
+        eng.radius(QUERIES, 60.0)
+        eng.add_sparse(*coo)
+        eng.remove(np.arange(3))
+    [top] = cap.named("engine.topk")
+    [walk] = cap.named("allpairs.walk")
+    [part] = cap.named("partition.topk")
+    assert walk.within(part) and part.within(top)
+    assert walk.args["k"] == 5
+    rounds = cap.named("allpairs.walk.plan")
+    assert rounds
+    for kind in ("plan", "gather", "score", "merge"):
+        spans = cap.named(f"allpairs.walk.{kind}")
+        assert spans and all(e.within(walk) for e in spans), kind
+    [sync] = [e for e in cap.named("engine.query_sync") if e.within(top)]
+    assert sync.end_ns <= walk.start_ns
+    [rad] = cap.named("engine.radius")
+    for name in ("engine.sketch", "engine.query_sync",
+                 "partition.radius_tiers", "allpairs.threshold_pairs",
+                 "partition.radius_group"):
+        assert any(e.within(rad) for e in cap.named(name)), name
+    [add] = cap.named("engine.add_sparse")
+    for name in ("engine.sketch", "store.add", "engine.track_drift"):
+        assert any(e.within(add) for e in cap.named(name)), name
+    [rm] = cap.named("engine.remove")
+    [srm] = cap.named("store.remove")
+    assert srm.within(rm) and srm.args["rows"] == 3
+
+
+@requires_obs
+def test_xla_events_of_a_query_fall_inside_its_engine_span():
+    """One clock: JAX's own host events (dispatch) and the XLA ops the CPU
+    backend runs for a query lie inside the query's `engine.topk` span."""
+    eng = QueryEngine(P, band_rows=8, cache_entries=0)
+    eng.add_dense(X)
+    eng.topk(QUERIES, 5)
+    with capture() as cap:
+        eng.topk(QUERIES, 5)
+    [top] = cap.named("engine.topk")
+    dispatch = [e for e in cap.events if e.name.startswith("PjitFunction")]
+    xla_ops = [e for e in cap.events if "hlo_op" in e.args]
+    assert dispatch and xla_ops
+    for e in dispatch + xla_ops:
+        assert e.within(top), (e.name, e.start_ns, e.end_ns, top)
+
+
+@requires_obs
+def test_walk_rounds_counter_equals_the_walks_rounds():
+    """`index_walk_rounds_total` counts the band rounds the walk ran: one
+    `allpairs.walk.plan` span each."""
+    eng = QueryEngine(P, band_rows=4, cache_entries=0)
+    eng.add_dense(X)
+    eng.topk(QUERIES, 5)
+    before = eng.obs_snapshot()["index_walk_rounds_total"]
+    with capture() as cap:
+        eng.topk(QUERIES, 5)
+    moved = eng.obs_snapshot()["index_walk_rounds_total"] - before
+    plans = cap.named("allpairs.walk.plan")
+    assert moved == len(plans) >= 2
+    assert sorted(e.args["round"] for e in plans) == list(
+        range(1, moved + 1))
+
+
+@requires_obs
+def test_fresh_jit_shape_counts_a_compile():
+    """The compile listener counts a jit's compile phases into the
+    process-default registry and marks each with an `obs.compile`
+    instant carrying its duration."""
+    import jax
+    import jax.numpy as jnp
+
+    reg = obs.get_registry()
+
+    def backend(snap, key):
+        return snap.get(key, {}).get("phase=backend_compile", 0)
+
+    before = reg.snapshot()
+    with capture() as cap:
+        jax.jit(lambda v: v * 3 + 1)(jnp.ones(4099)).block_until_ready()
+    after = reg.snapshot()
+    assert backend(after, "jax_compiles_total") >= \
+        backend(before, "jax_compiles_total") + 1
+    assert backend(after, "jax_compile_seconds_total") > \
+        backend(before, "jax_compile_seconds_total")
+    marks = cap.named("obs.compile")
+    assert {"jaxpr_trace", "backend_compile"} <= {
+        e.args["phase"] for e in marks}
+    assert all(e.args["us"] >= 0 for e in marks)
+
+
+def test_disabled_switch_records_no_program_span(obs_restore):
+    """`obs.configure(False)`: a capture of a serving journey holds JAX's
+    own events and not one program span or instant."""
+    obs.configure(False)
+    eng = QueryEngine(P, band_rows=8, cache_entries=0)
+    with capture() as cap:
+        eng.add_dense(X[:32])
+        eng.topk(QUERIES, 5)
+        eng.radius(QUERIES, 60.0)
+    assert cap.names()
+    assert _program_spans(cap) == set()
 
 
 # ---------------------------------------------------------------------------

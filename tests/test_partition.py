@@ -225,12 +225,13 @@ def test_reshard_changes_topology_not_answers():
 
 
 def test_partition_gauges_and_merge_span_shapes():
-    from repro import obs
+    from _capture import capture
 
     eng = QueryEngine(P, band_rows=8, cache_entries=0)
     eng.add_dense(X[:24])
     eng.shard(n_shards=2)
-    eng.topk(QUERIES, 4)
+    with capture() as cap:
+        eng.topk(QUERIES, 4)
     if eng.obs.is_null:  # REPRO_OBS=0: the instruments are no-ops
         pytest.skip("obs disabled in this environment")
     text = eng.render_prom()
@@ -240,8 +241,11 @@ def test_partition_gauges_and_merge_span_shapes():
     for kind in ("sorted-banded", "brute-delta"):
         assert f'kind="{kind}"' in text
     assert 'role="serve"' in text and 'device="host"' in text
-    names = {e["name"] for e in obs.trace_events()}
-    assert "partition.merge" in names
+    # the cross-partition walk: one span over both shards' walks
+    [walk] = cap.named("partition.topk")
+    assert walk.args["shards"] == 2
+    assert "partition.merge" not in cap.names()
+    assert all(e.within(walk) for e in cap.named("allpairs.walk"))
 
 
 def test_reshard_frees_the_old_layout():
