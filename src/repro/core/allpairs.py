@@ -637,6 +637,63 @@ def topk_rows(a, b, k: int, *, d: int, metric: str = "cham",
     return np.asarray(idxs), np.asarray(vals)
 
 
+@functools.partial(
+    jax.jit, static_argnames=("k", "tile", "width", "metric", "mode", "d"))
+def _topk_tiles_impl(a, b, rank, tiles, count, *, k, tile, width, metric,
+                     mode, d):
+    # the jnp twin of kernels.topk_select.topk_select_tiles: the listed
+    # tiles of b scored in place, ties broken by rank, rank -1 never
+    # returned.  `count` is traced, so one graph serves every round.  A
+    # step scores width // tile listed tiles side by side, `width` being
+    # the tile topk_rows takes over all of b (min(block, len(b))): XLA's
+    # CPU code rounds the estimator differently at some tile widths (4
+    # and 8 columns against 16 or more), so the walk keeps topk_rows' bits.
+    from repro.kernels.topk_select.kernel import (
+        _KEY_EMPTY, _NO_IDENT, _key_value, _sort_key)
+
+    n = a.shape[0]
+    group = width // tile
+    offs = jnp.arange(tile, dtype=jnp.int32)
+
+    def body(s, carry):
+        keys, ranks = carry  # (n, k) ascending by (sort key, rank)
+        j = s * group + jnp.arange(group, dtype=jnp.int32)
+        rows = (tiles[j][:, None] * tile + offs).reshape(-1)
+        r = jnp.where(jnp.repeat(j < count, tile), rank[0, rows], -1)[None]
+        live = r >= 0
+        dist = _tile_dist(a, b[rows], d, metric, mode)
+        key = jnp.where(live, _sort_key(dist), _KEY_EMPTY)
+        ident = jnp.broadcast_to(jnp.where(live, r, _NO_IDENT), key.shape)
+        keys, ranks = jax.lax.sort(
+            (jnp.concatenate([keys, key], axis=1),
+             jnp.concatenate([ranks, ident], axis=1)),
+            dimension=1, num_keys=2)
+        return keys[:, :k], ranks[:, :k]
+
+    keys = jnp.full((n, k), _KEY_EMPTY, jnp.int32)
+    ranks = jnp.full((n, k), _NO_IDENT, jnp.int32)
+    keys, ranks = jax.lax.fori_loop(0, (count + group - 1) // group, body,
+                                    (keys, ranks))
+    empty = ranks == _NO_IDENT
+    return (jnp.where(empty, jnp.inf, _key_value(keys)),
+            jnp.where(empty, -1, ranks))
+
+
+def key_rank(keys: np.ndarray, n_rows: int,
+             alive: np.ndarray | None = None
+             ) -> tuple[np.ndarray, np.ndarray]:
+    """(rank (n_rows,) int32, pos_of_rank (len(keys),) int64): each of the
+    first len(keys) rows' position in ascending (stable) key order, and its
+    inverse.  Rows past len(keys), and rows `alive` marks dead, rank -1 —
+    the in-place walk never returns them."""
+    pos_of_rank = np.argsort(keys, kind="stable")
+    rank = np.full(n_rows, -1, np.int32)
+    rank[pos_of_rank] = np.arange(len(keys), dtype=np.int32)
+    if alive is not None:
+        rank[: len(keys)][~alive[: len(keys)]] = -1
+    return rank, pos_of_rank
+
+
 def topk_rows_banded(a, b, k: int, *, d: int, q_scores: np.ndarray,
                      band_lo: np.ndarray, band_hi: np.ndarray,
                      band_rows: int, n_valid: int, metric: str = "cham",
@@ -646,7 +703,10 @@ def topk_rows_banded(a, b, k: int, *, d: int, q_scores: np.ndarray,
                      alive: np.ndarray | None = None,
                      stats_out: dict | None = None,
                      deadline=None,
-                     init_kth: np.ndarray | None = None):
+                     init_kth: np.ndarray | None = None,
+                     weights: jnp.ndarray | None = None,
+                     rank: jnp.ndarray | None = None,
+                     pos_of_rank: np.ndarray | None = None):
     """Progressive band-expansion top-k over weight-banded rows.
 
     `b` holds `n_valid` rows sorted by ascending prune score and cut into
@@ -662,23 +722,30 @@ def topk_rows_banded(a, b, k: int, *, d: int, q_scores: np.ndarray,
     holds for every query and unvisited band: any unseen row is then
     provably strictly farther than the current k-th neighbour (the strict
     margin also settles knife-edge ties), so the answer equals the full
-    scan's.  Visited chunks double in row count, and each chunk is gathered
-    to a power-of-two shape, so one query compiles O(log N) graphs and
-    touches O(answer neighbourhood) rows instead of O(N).
+    scan's.  Rounds double in row count.  Each round's bands are scored
+    where they lie in b: the round's band numbers become a list of tiles
+    (gcd(band_rows, len(b), block) rows each) for one top-k call over the
+    whole of b, whose program depends on the query bucket, len(b) and k
+    alone — nothing is gathered, and no round compiles.
 
     `order_by` assigns each row the tie-break key the results must honour
-    (repro.index passes external ids; default: row position).  Within each
-    chunk columns are laid out in ascending key order, so the tile merge's
-    lower-column tie-break IS the key tie-break, and the host-side merge
-    across chunks is an exact (value, key)-lexicographic k-best.
+    (repro.index passes external ids; default: row position).  The top-k
+    call breaks distance ties by each row's RANK in ascending key order, so
+    across tiles listed in any order it returns the (value, key)-
+    lexicographic k-best, and the host-side merge across rounds is exact.
 
     `alive` optionally masks rows out (bool over the n_valid sorted rows —
-    the tiered layout's tombstones): dead rows are dropped on host before
-    each chunk gather, so they cost no device work and can never be
+    the tiered layout's tombstones): dead rows rank -1 and can never be
     returned.  The band score intervals are computed over the UNMASKED
     rows, which makes them conservative supersets for the alive subset —
     the certificate under-prunes but stays sound, and the result equals
     `topk_rows` over just the alive rows in key order.
+
+    `weights`, `rank` and `pos_of_rank` are what a caller that walks the
+    same rows repeatedly builds once (repro.index.BandedLayout): int32
+    (1, len(b)) rows of popcounts and ranks (`key_rank` of `order_by`, with
+    `alive` applied), and the rank -> position map.  Left out, they are
+    built here from `order_by` and `alive`.
 
     `deadline` (any object with an `expired` property — repro.serve's
     Deadline) turns the walk into a budgeted one: between rounds, an
@@ -705,6 +772,10 @@ def topk_rows_banded(a, b, k: int, *, d: int, q_scores: np.ndarray,
     unfilled columns carry position -1 / value inf even in exact
     (non-partial) results, and merge away against any real candidate.
 
+    `stats_out` also counts the top-k calls' grid steps: `tiles_scored`
+    (listed tiles) and `tiles_skipped` (the rest of each call's fixed
+    grid of len(b) / tile steps).
+
     Returns (positions (Q, k) int64 into b's rows, distances (Q, k) f32) —
     bit-identical to `topk_rows` over the same rows arranged in key order.
     Positions can be -1 (column unfilled) only in a partial result or
@@ -712,24 +783,51 @@ def topk_rows_banded(a, b, k: int, *, d: int, q_scores: np.ndarray,
     """
     a = jnp.asarray(a)
     q = a.shape[0] if q_valid is None else q_valid
-    n_live = n_valid if alive is None else int(
-        np.count_nonzero(alive[:n_valid]))
-    k = min(k, n_live)
+    n_bands = len(band_lo)
+    starts = np.arange(n_bands) * band_rows
+    band_size = np.minimum(band_rows, n_valid - starts)
+    band_live = (band_size if alive is None or n_bands == 0 else
+                 np.add.reduceat(alive[:n_valid], starts, dtype=np.int64))
+    k = min(k, int(band_live.sum()))
     if stats_out is not None:
         # filled below; pre-set so early returns still report a full record
-        stats_out.update(n_bands=len(band_lo), bands_visited=0,
+        stats_out.update(n_bands=n_bands, bands_visited=0,
                          rows_visited=0, rounds=0, early_stop=False,
-                         partial=False, cert_gap=0.0)
+                         partial=False, cert_gap=0.0, tiles_scored=0,
+                         tiles_skipped=0)
     if q == 0 or k == 0:
         return np.zeros((q, 0), np.int64), np.zeros((q, 0), np.float32)
-    # one span for the walk; each round is four: `allpairs.walk.plan`
-    # (bands, rows, key order), `.gather` (the padded device take),
-    # `.score` (topk_rows up to its host copy: where the host waits on
-    # the device) and `.merge` (the k-best merge and the certificate)
-    with obs.span("allpairs.walk", bands=len(band_lo), q=q, k=k):
+    # one span for the walk; each round is three: `allpairs.walk.plan`
+    # (the round's bands and their tile list), `.score` (the top-k call up
+    # to its host copy: where the host waits on the device) and `.merge`
+    # (the k-best merge and the certificate)
+    with obs.span("allpairs.walk", bands=n_bands, q=q, k=k):
+        if rank is None:
+            keys = (np.arange(n_valid) if order_by is None
+                    else np.asarray(order_by)[:n_valid])
+            rank_h, pos_of_rank = key_rank(keys, b.shape[0], alive)
+            rank = jnp.asarray(rank_h[None, :])
+        tile = math.gcd(math.gcd(band_rows, b.shape[0]), block)
+        if _auto_mode(mode) == "pallas":
+            from repro.kernels.topk_select import kernel as _tk
+
+            if weights is None:
+                weights = packing.popcount_rows(b)[None, :]
+            score = functools.partial(
+                _tk.topk_select_tiles, a, b, weights, rank, k=k,
+                metric=metric, d=d, bn=tile,
+                interpret=jax.default_backend() != "tpu")
+        else:
+            score = functools.partial(
+                _topk_tiles_impl, a, b, rank, k=k, tile=tile,
+                width=min(block, b.shape[0]), metric=metric,
+                mode=_auto_mode(mode), d=d)
+        tiles_per_band = band_rows // tile
+        n_tiles = b.shape[0] // tile
+        live_tiles = -(-n_valid // tile)  # tiles past it hold padding only
+        tiles = np.zeros(n_tiles, np.int32)
         q_scores = np.asarray(q_scores, np.float64)
         factor = prune_factor(metric)
-        n_bands = len(band_lo)
         # per-(query, band) weight-bound gaps; visit priority = nearest first
         gap = np.maximum(np.maximum(band_lo[None, :] - q_scores[:, None],
                                     q_scores[:, None] - band_hi[None, :]), 0.0)
@@ -748,13 +846,10 @@ def topk_rows_banded(a, b, k: int, *, d: int, q_scores: np.ndarray,
         best_key = np.full((q, k), KBEST_KEY_PAD, np.int64)
         best_pos = np.full((q, k), -1, np.int64)
 
-        def band_range(bb: int) -> np.ndarray:
-            return np.arange(bb * band_rows,
-                             min((bb + 1) * band_rows, n_valid))
-
         ptr = 0
         visited_rows = 0
         rounds = 0
+        tiles_scored = tiles_skipped = 0
         while ptr < n_bands:
             rounds += 1
             with obs.span("allpairs.walk.plan", round=rounds):
@@ -769,41 +864,31 @@ def topk_rows_banded(a, b, k: int, *, d: int, q_scores: np.ndarray,
                         ptr += 1
                 else:
                     target = max(visited_rows, band_rows)  # geometric growth
-                    cnt = len(band_range(take[0]))
+                    cnt = band_size[take[0]]
                     while ptr < n_bands and cnt < target:
                         take.append(visit[ptr])
-                        cnt += len(band_range(visit[ptr]))
+                        cnt += band_size[visit[ptr]]
                         ptr += 1
-                rows = np.concatenate([band_range(bb) for bb in take])
-                if alive is not None:
-                    rows = rows[alive[rows]]  # tombstones reach no tile
-                visited_rows += len(rows)
-                if len(rows):
-                    keys = (rows if order_by is None
-                            else np.asarray(order_by)[rows])
-                    rows = rows[np.argsort(keys, kind="stable")]  # key order
-            if len(rows):
-                with obs.span("allpairs.walk.gather", rows=len(rows)):
-                    sub = packing.padded_take(b, rows)
-                kk = min(k, len(rows))
-                with obs.span("allpairs.walk.score", rows=len(rows)):
-                    pos_c, val_c = topk_rows(a, sub, kk, d=d, metric=metric,
-                                             block=block, mode=mode,
-                                             m_valid=len(rows))
+                live = int(band_live[take].sum())
+                visited_rows += live
+                listed = (np.asarray(take)[:, None] * tiles_per_band
+                          + np.arange(tiles_per_band)).ravel()
+                listed = listed[listed < live_tiles]
+                count = len(listed)
+                tiles[:count] = listed
+            if live:  # a round of dead rows only scores nothing
+                with obs.span("allpairs.walk.score", tiles=count):
+                    val_c, rank_c = score(tiles, np.int32(count))
+                    val_c = np.asarray(val_c)[:q]
+                    rank_c = np.asarray(rank_c)[:q].astype(np.int64)
+                tiles_scored += count
+                tiles_skipped += n_tiles - count
             with obs.span("allpairs.walk.merge", round=rounds):
-                if len(rows):
-                    gpos = rows[pos_c[:q]]
-                    gkey = (gpos if order_by is None
-                            else np.asarray(order_by)[gpos])
-                    if kk < k:  # pad the chunk's candidate list to k columns
-                        padw = ((0, 0), (0, k - kk))
-                        val_c = np.pad(val_c[:q], padw,
-                                       constant_values=np.inf)
-                        gpos = np.pad(gpos, padw, constant_values=-1)
-                        gkey = np.pad(gkey, padw,
-                                      constant_values=KBEST_KEY_PAD)
-                    else:
-                        val_c = val_c[:q]
+                if live:
+                    # rank order is key order, so ranks serve as merge keys
+                    real = rank_c >= 0
+                    gpos = np.where(real, pos_of_rank[rank_c], -1)
+                    gkey = np.where(real, rank_c, KBEST_KEY_PAD)
                     best_v, best_key, best_pos = kbest_lex_merge(
                         k, np.concatenate([best_v, val_c], axis=1),
                         np.concatenate([best_key, gkey], axis=1),
@@ -830,9 +915,9 @@ def topk_rows_banded(a, b, k: int, *, d: int, q_scores: np.ndarray,
                             kth[:, None] + PRUNE_MARGIN - bound, 0.0)))
                     break
         if stats_out is not None:
-            stats_out["bands_visited"] = ptr
-            stats_out["rows_visited"] = visited_rows
-            stats_out["rounds"] = rounds
+            stats_out.update(bands_visited=ptr, rows_visited=visited_rows,
+                             rounds=rounds, tiles_scored=tiles_scored,
+                             tiles_skipped=tiles_skipped)
         return best_pos, best_v
 
 

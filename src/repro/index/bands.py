@@ -38,9 +38,14 @@ import jax.numpy as jnp
 from repro.core import allpairs
 from repro.core.allpairs import (KBEST_KEY_PAD, PRUNE_MARGIN, prune_factor,
                                  prune_score_host)
-from repro.core.packing import padded_take
+from repro.core.packing import padded_take, pow2_bucket
 from repro.index.store import SketchStore
 from repro.obs.registry import NULL_REGISTRY
+
+
+@jax.jit
+def _drop_ranks(rank: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
+    return rank.at[0, pos].set(-1)
 
 
 class BandedLayout:
@@ -56,7 +61,8 @@ class BandedLayout:
 
     The snapshot itself never mutates; later tombstones are threaded
     through `refresh_alive`, which re-reads the store's host bitmap at the
-    snapshot's slots (O(n) host work, no device traffic).  Band score
+    snapshot's slots (O(n) host work) and drops the newly dead rows' ranks
+    in one device scatter.  Band score
     intervals are computed over the snapshot's rows and therefore stay
     conservative supersets for any alive subset — masked queries prune a
     little less but never wrongly.
@@ -77,6 +83,9 @@ class BandedLayout:
         self._c_pruned = reg.counter("index_bands_pruned_total")
         self._c_rounds = reg.counter("index_walk_rounds_total")
         self._c_early = reg.counter("index_band_early_stops_total")
+        self._c_scored = reg.counter("index_walk_tiles_total", state="scored")
+        self._c_skipped = reg.counter("index_walk_tiles_total",
+                                      state="skipped")
         self.metric = metric
         self.d = store.d
         self.band_rows = int(band_rows)
@@ -95,8 +104,17 @@ class BandedLayout:
         self.ids = store.ids_at(slots)[order]
         w_sorted = weights[order]
         self.matrix = padded_take(store.sk_buf, self.slots)
+        # the top-k walk scores bands in place: beside the matrix, each
+        # row's popcount and its rank in id order (-1: dead or padding),
+        # and the rank -> position map its merge reads
+        n_pad = self.matrix.shape[0]
+        rank, self.pos_of_rank = allpairs.key_rank(self.ids, n_pad)
+        self.rank = jnp.asarray(rank[None, :])
+        self.weights = jnp.asarray(np.pad(
+            w_sorted.astype(np.int32), (0, n_pad - self.n))[None, :])
         if device is not None:
-            self.matrix = jax.device_put(self.matrix, device)
+            self.matrix, self.rank, self.weights = jax.device_put(
+                (self.matrix, self.rank, self.weights), device)
         self.alive = np.ones(self.n, bool)
         self._n_alive = self.n
         self.n_bands = -(-self.n // self.band_rows) if self.n else 0
@@ -113,9 +131,18 @@ class BandedLayout:
 
     def refresh_alive(self, store: SketchStore) -> None:
         """Re-read the store's tombstone bitmap at this snapshot's slots —
-        how removes reach a layout without any rebuild or device work."""
+        how removes reach a layout without any rebuild: the newly dead
+        rows' ranks drop to -1 in one device scatter (tombstones never
+        resurrect)."""
         if self.n:
-            self.alive = store.alive_at(self.slots)
+            alive = store.alive_at(self.slots)
+            dead = np.flatnonzero(self.alive & ~alive)
+            if len(dead):
+                # pow2-padded with repeats: one compiled scatter per bucket
+                pos = np.full(pow2_bucket(len(dead)), dead[0], np.int32)
+                pos[: len(dead)] = dead
+                self.rank = _drop_ranks(self.rank, pos)
+            self.alive = alive
             self._n_alive = int(np.count_nonzero(self.alive))
 
     def _mask(self) -> np.ndarray | None:
@@ -175,14 +202,17 @@ class BandedLayout:
         pos, vals = allpairs.topk_rows_banded(
             queries_padded, self.matrix, k, d=self.d, metric=self.metric,
             q_scores=qs, band_lo=self.band_lo, band_hi=self.band_hi,
-            band_rows=self.band_rows, n_valid=self.n, order_by=self.ids,
-            block=block, mode=mode, q_valid=q_valid, alive=self._mask(),
-            stats_out=st, deadline=deadline, init_kth=init_kth)
+            band_rows=self.band_rows, n_valid=self.n, block=block,
+            mode=mode, q_valid=q_valid, alive=self._mask(), stats_out=st,
+            deadline=deadline, init_kth=init_kth, weights=self.weights,
+            rank=self.rank, pos_of_rank=self.pos_of_rank)
         if st is not None and not self._obs_off:
             self._c_queries.inc()
             self._c_visited.inc(st["bands_visited"])
             self._c_pruned.inc(st["n_bands"] - st["bands_visited"])
             self._c_rounds.inc(st["rounds"])
+            self._c_scored.inc(st["tiles_scored"])
+            self._c_skipped.inc(st["tiles_skipped"])
             if st["early_stop"]:
                 self._c_early.inc()
         if info_out is not None and st is not None:

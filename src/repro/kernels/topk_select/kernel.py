@@ -44,6 +44,16 @@ compiled program at any tile size.
 Grid: (Q/BQ, N/BN) with the column dimension innermost; `m` (the traced
 valid-column count) rides in SMEM so varying the live store size never
 recompiles.
+
+In-place entry (`topk_select_tiles`, the band walk's): b is a whole
+resident layout and a scalar-prefetched tile list names the blocks to
+score, so a round of bands is scored where it lies — no gathered chunk,
+and one program per (Q, P, k) whatever the round holds.  The same merge
+body runs with the row's RANK (from a (1, P) row beside b) as the carry's
+identity instead of its column: the (key, rank) minimum over tiles in any
+order equals the (key, column) minimum over the same rows laid out in
+rank order.  Rank -1 (dead rows, padding) keys as empty and never enters
+the carry.
 """
 
 from __future__ import annotations
@@ -62,6 +72,7 @@ _SIGN_FREE = 0x7FFFFFFF
 _KEY_INF = 0x7F800000  # sort key of +inf
 _KEY_EMPTY = 0x7FFFFFFF  # above every real key, NaN included
 _CHUNK = 512  # columns per merge chunk
+_NO_IDENT = 2**31 - 1  # identity ranking after every real column or rank
 
 
 def _sort_key(x: jnp.ndarray) -> jnp.ndarray:
@@ -101,38 +112,37 @@ def _distances(wq, wb, inner, metric: str, d: int) -> jnp.ndarray:
     raise ValueError(f"unknown metric {metric!r}")
 
 
-def _topk_select_kernel(m_ref, q_ref, wq_ref, b_ref, wb_ref, keys_ref,
-                        idxs_ref, *, k, bn, chunk, metric, d):
-    """One (BQ, BN) column step of the running (BQ, k) select."""
-    j = pl.program_id(1)
+def _init_carry(keys_ref, idxs_ref):
+    keys_ref[...] = jnp.full_like(keys_ref, _KEY_EMPTY)
+    idxs_ref[...] = jnp.full_like(idxs_ref, -1)
 
-    @pl.when(j == 0)
-    def _init():
-        keys_ref[...] = jnp.full_like(keys_ref, _KEY_EMPTY)
-        idxs_ref[...] = jnp.full_like(idxs_ref, -1)
 
+def _merge_block(q_ref, wq_ref, b_ref, wb_ref, keys_ref, idxs_ref, label, *,
+                 k, bn, chunk, metric, d):
+    """Merge one (BN, W) block of rows into the resident (BQ, k) carry.
+
+    `label(off, dist)` gives the columns of the chunk at offset `off` their
+    sort keys and identities: the identity breaks key ties (lower wins) and
+    is what the carry's index tile records."""
     q = q_ref[...]
     wq = wq_ref[...]  # (BQ, 1)
-    m = m_ref[0, 0]
     bq = q.shape[0]
     kiota = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
-    big = jnp.int32(2**31 - 1)
+    big = jnp.int32(_NO_IDENT)
 
     def merge_chunk(off, carry):
         keys, idxs = carry  # (BQ, k) ascending by (key, index)
         inner = _inner(q, b_ref[pl.ds(off, chunk), :])
         dist = _distances(wq, wb_ref[:, pl.ds(off, chunk)], inner, metric, d)
-        col = (j * bn + off
-               + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1))
-        key = jnp.where(col < m, _sort_key(dist), _KEY_INF)
+        key, ident = label(off, dist)
 
         def extract(_, st):
             key, keys, idxs = st
-            # lexicographic (key, column) chunk minimum
+            # lexicographic (key, identity) chunk minimum
             tmin = jnp.min(key, axis=1, keepdims=True)
-            tidx = jnp.min(jnp.where(key == tmin, col, big), axis=1,
+            tidx = jnp.min(jnp.where(key == tmin, ident, big), axis=1,
                            keepdims=True)
-            key = jnp.where(col == tidx, _KEY_EMPTY, key)
+            key = jnp.where(ident == tidx, _KEY_EMPTY, key)
             # compare-exchange insertion: strictly-smaller carry entries
             # stay, the tail shifts right one slot, the extracted pair drops
             # in.  An insertion past the end (pos == k) leaves the carry
@@ -161,6 +171,48 @@ def _topk_select_kernel(m_ref, q_ref, wq_ref, b_ref, wb_ref, keys_ref,
             carry)
     keys_ref[...] = keys
     idxs_ref[...] = idxs
+
+
+def _topk_select_kernel(m_ref, q_ref, wq_ref, b_ref, wb_ref, keys_ref,
+                        idxs_ref, *, k, bn, chunk, metric, d):
+    """One (BQ, BN) column step of the running (BQ, k) select."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        _init_carry(keys_ref, idxs_ref)
+
+    m = m_ref[0, 0]
+
+    def label(off, dist):  # identity = column; columns past m rank as +inf
+        col = (j * bn + off
+               + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1))
+        return jnp.where(col < m, _sort_key(dist), _KEY_INF), col
+
+    _merge_block(q_ref, wq_ref, b_ref, wb_ref, keys_ref, idxs_ref, label,
+                 k=k, bn=bn, chunk=chunk, metric=metric, d=d)
+
+
+def _topk_tiles_kernel(tiles_ref, count_ref, q_ref, wq_ref, b_ref, wb_ref,
+                       rank_ref, keys_ref, idxs_ref, *, k, bn, chunk, metric,
+                       d):
+    """Grid step j scores listed tile `tiles[j]` while j < count."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        _init_carry(keys_ref, idxs_ref)
+
+    def label(off, dist):  # identity = rank; rank -1 never enters the carry
+        r = rank_ref[:, pl.ds(off, chunk)]  # (1, chunk)
+        live = r >= 0
+        return (jnp.where(live, _sort_key(dist), _KEY_EMPTY),
+                jnp.broadcast_to(jnp.where(live, r, _NO_IDENT), dist.shape))
+
+    @pl.when(j < count_ref[0])
+    def _score():
+        _merge_block(q_ref, wq_ref, b_ref, wb_ref, keys_ref, idxs_ref, label,
+                     k=k, bn=bn, chunk=chunk, metric=metric, d=d)
 
 
 @functools.partial(
@@ -216,5 +268,78 @@ def topk_select(
         ],
         interpret=interpret,
     )(m_arr, q_p, wq, b_p, wb)
+    keys, idxs = keys[:nq], idxs[:nq]
+    return jnp.where(idxs < 0, jnp.inf, _key_value(keys)), idxs
+
+
+@functools.partial(
+    jax.jit, static_argnames=("k", "metric", "d", "bq", "bn", "interpret"))
+def topk_select_tiles(
+    q: jnp.ndarray,
+    b: jnp.ndarray,
+    weights: jnp.ndarray,
+    rank: jnp.ndarray,
+    tiles: jnp.ndarray,
+    count,
+    k: int,
+    *,
+    metric: str = "cham",
+    d: int,
+    bq: int = 128,
+    bn: int = 1024,
+    interpret: bool = False,
+):
+    """Fused k-nearest rows over listed tiles of a resident matrix, in
+    place: q (Q, W) x the tiles `tiles[:count]` of b (P, W) ->
+    (values (Q, k) f32, ranks (Q, k) int32), ascending by (value, rank).
+
+    Tile t is rows [t * bn, (t + 1) * bn) of b; `weights` and `rank` are
+    int32 (1, P) rows beside b: each row's popcount, and its rank in the
+    caller's tie-break order, or -1 for a row that must never be returned
+    (dead, or padding).  `tiles` is int32 (P // bn,) and `count` is traced,
+    so the program depends on (Q, P, k) alone, whatever a call lists.
+    Grid steps past `count` revisit the last listed tile (no new block is
+    fetched) and skip their compute.  Slots left without a live row read
+    (inf, -1).  Requires P % bn == 0, and on a TPU bn a multiple of 128
+    or P itself (the (1, bn) weight and rank blocks are lane-tiled).
+    """
+    assert q.ndim == 2 and b.ndim == 2 and q.shape[1] == b.shape[1]
+    nq, w = q.shape
+    n_tiles = b.shape[0] // bn
+    assert n_tiles * bn == b.shape[0] and tiles.shape == (n_tiles,)
+    assert weights.shape == rank.shape == (1, b.shape[0])
+    bq_ = min(bq, nq)
+    chunk = _CHUNK if bn % _CHUNK == 0 else bn
+    q_p = pad_to_multiple(q, bq_, 0)
+    wq = popcount_rows(q_p)[:, None]
+    cnt = jnp.asarray(count, jnp.int32).reshape(1)
+
+    def tile(j, tiles, cnt):  # steps past count stay on the last listed tile
+        return tiles[jnp.minimum(j, jnp.maximum(cnt[0] - 1, 0))]
+
+    keys, idxs = pl.pallas_call(
+        functools.partial(_topk_tiles_kernel, k=k, bn=bn, chunk=chunk,
+                          metric=metric, d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(q_p.shape[0] // bq_, n_tiles),
+            in_specs=[
+                pl.BlockSpec((bq_, w), lambda i, j, t, c: (i, 0)),
+                pl.BlockSpec((bq_, 1), lambda i, j, t, c: (i, 0)),
+                pl.BlockSpec((bn, w), lambda i, j, t, c: (tile(j, t, c), 0)),
+                pl.BlockSpec((1, bn), lambda i, j, t, c: (0, tile(j, t, c))),
+                pl.BlockSpec((1, bn), lambda i, j, t, c: (0, tile(j, t, c))),
+            ],
+            out_specs=[
+                pl.BlockSpec((bq_, k), lambda i, j, t, c: (i, 0)),
+                pl.BlockSpec((bq_, k), lambda i, j, t, c: (i, 0)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((q_p.shape[0], k), jnp.int32),
+            jax.ShapeDtypeStruct((q_p.shape[0], k), jnp.int32),
+        ],
+        interpret=interpret,
+    )(tiles.astype(jnp.int32), cnt, q_p, wq, b, weights, rank)
     keys, idxs = keys[:nq], idxs[:nq]
     return jnp.where(idxs < 0, jnp.inf, _key_value(keys)), idxs

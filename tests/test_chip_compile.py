@@ -92,6 +92,18 @@ def test_topk_select_compiles(shape, metric):
         shape((128, w)), shape((8192, w)), shape((), jnp.int32))
 
 
+@pytest.mark.parametrize("q", [8, 64])
+def test_topk_select_in_place_compiles(shape, q):
+    """The band walk's in-place entry at the PubMed layout's size: 2^23
+    rows, 1,024-row tiles, the tile list in SMEM."""
+    w, p, bn = 128, 2**23, 1024
+    _compile_for_chip(
+        lambda q_, b, wt, r, t, n: topk_kernel.topk_select_tiles(
+            q_, b, wt, r, t, n, 10, metric="cham", d=32 * w, bn=bn),
+        shape((q, w)), shape((p, w)), shape((1, p)), shape((1, p)),
+        shape((p // bn,)), shape((), jnp.int32))
+
+
 def test_estimator_log_compiles_in_a_kernel(shape):
     """On TPU the Cham estimator takes its log from `log_f32` (bit ops,
     multiplies and adds), inside the top-k kernel as well."""

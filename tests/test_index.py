@@ -389,18 +389,18 @@ def test_banded_topk_certificate_prunes_but_never_drops(metric, monkeypatch):
     eng = QueryEngine(P, metric=metric, band_rows=8, cache_entries=0)
     eng.add_dense(X)
     visited = []
-    orig = ap.topk_rows
+    orig = ap._topk_tiles_impl
 
-    def counting(a, b, k, **kw):
-        visited.append(kw.get("m_valid", np.shape(b)[0]))
-        return orig(a, b, k, **kw)
+    def counting(a, b, rank, tiles, count, **kw):
+        visited.append(int(count) * kw["tile"])  # rows of the listed tiles
+        return orig(a, b, rank, tiles, count, **kw)
 
-    monkeypatch.setattr(ap, "topk_rows", counting)
+    monkeypatch.setattr(ap, "_topk_tiles_impl", counting)
     weights = np_popcount_rows(SK)
     qi = int(np.argmin(weights))  # narrowest sketch: strongest certificate
     got_i, got_v = eng.topk(X[qi: qi + 1], 3)
     assert 0 < sum(visited) < len(X)  # the certificate actually fired
-    monkeypatch.setattr(ap, "topk_rows", orig)
+    monkeypatch.setattr(ap, "_topk_tiles_impl", orig)
     ref_i, ref_v = topk_rows(SK[qi: qi + 1], SK, 3, d=D, metric=metric)
     np.testing.assert_array_equal(got_i, ref_i)
     np.testing.assert_array_equal(got_v, ref_v)
@@ -409,6 +409,64 @@ def test_banded_topk_certificate_prunes_but_never_drops(metric, monkeypatch):
     ref5 = topk_rows(SK[:5], SK, 7, d=D, metric=metric)
     np.testing.assert_array_equal(got5[0], ref5[0])
     np.testing.assert_array_equal(got5[1], ref5[1])
+
+
+class _Expired:
+    expired = True
+
+
+@pytest.mark.parametrize("case", ["plain", "tombstones", "init_kth",
+                                  "deadline"])
+@pytest.mark.parametrize("metric", ["cham", "hamming"])
+def test_in_place_walk_equals_full_scan_in_id_order(metric, case):
+    """The walk scores each round's bands in place, as a tile list, and
+    returns what the full scan over the alive rows in id order returns:
+    with copies of rows straddling band cuts (equal distances across
+    tiles resolve to the lower id), after tombstones reach the layout
+    through `refresh_alive`, under an `init_kth` bound at the true k-th
+    distance, and under an expired deadline (then: the full scan over the
+    first round's bands, those the weight bound cannot separate)."""
+    from repro.core.allpairs import prune_score_host
+    from repro.core.packing import np_popcount_rows, pad_rows_pow2
+
+    k, band_rows = 5, 8
+    x = np.concatenate([X[:40], np.repeat(X[40:52], 3, axis=0)])
+    sk = np.asarray(sketch_dense(P, jnp.asarray(x)))
+    eng = QueryEngine(P, metric=metric, band_rows=band_rows,
+                      merge_ratio=None, cache_entries=0)
+    ids = eng.add_dense(x)
+    layout = eng._banded_layout()
+    pos = np.argsort(layout.ids)  # id -> layout position
+    copies = pos[ids[40:]].reshape(12, 3) // band_rows
+    assert (copies.min(axis=1) < copies.max(axis=1)).any()  # cut copies
+    if case == "tombstones":
+        eng.remove(ids[[0, 41, 45, 60, 70]])
+        eng.sync_layout()
+        assert eng._banded_layout() is layout  # no rebuild
+        assert layout.n_alive == len(x) - 5
+        assert (np.asarray(layout.rank)[0, pos[ids[[0, 41]]]] == -1).all()
+    q = sk[[41, 44, 50, 3]]
+    qw = np_popcount_rows(q)
+    alive = eng.ids()
+    if case == "deadline":
+        qs = prune_score_host(qw, D, metric)
+        gap = np.maximum(np.maximum(layout.band_lo[None] - qs[:, None],
+                                    qs[:, None] - layout.band_hi[None]), 0)
+        first = np.flatnonzero(gap.min(axis=0) <= 0)
+        assert 0 < len(first) < layout.n_bands
+        rows = np.concatenate([np.arange(b * band_rows, (b + 1) * band_rows)
+                               for b in first])
+        alive = np.intersect1d(alive, layout.ids[rows[rows < layout.n]])
+    ref_i, ref_v = topk_rows(q, sk[alive], k, d=D, metric=metric)
+    info = {}
+    got_i, got_v = layout.topk(
+        pad_rows_pow2(jnp.asarray(q)), qw, k, q_valid=len(q),
+        info_out=info,
+        init_kth=ref_v[:, -1] if case == "init_kth" else None,
+        deadline=_Expired() if case == "deadline" else None)
+    np.testing.assert_array_equal(got_i, alive[ref_i])
+    np.testing.assert_array_equal(got_v, ref_v)
+    assert info["partial"] == (case == "deadline")
 
 
 def test_banded_layout_prunes_but_never_drops():

@@ -356,9 +356,10 @@ def test_walk_spans_nest_inside_engine_topk_and_layer_spans_exist():
     assert walk.args["k"] == 5
     rounds = cap.named("allpairs.walk.plan")
     assert rounds
-    for kind in ("plan", "gather", "score", "merge"):
+    for kind in ("plan", "score", "merge"):
         spans = cap.named(f"allpairs.walk.{kind}")
         assert spans and all(e.within(walk) for e in spans), kind
+    assert not cap.named("allpairs.walk.gather")  # rounds score in place
     [sync] = [e for e in cap.named("engine.query_sync") if e.within(top)]
     assert sync.end_ns <= walk.start_ns
     [rad] = cap.named("engine.radius")
@@ -406,6 +407,57 @@ def test_walk_rounds_counter_equals_the_walks_rounds():
     assert moved == len(plans) >= 2
     assert sorted(e.args["round"] for e in plans) == list(
         range(1, moved + 1))
+
+
+@requires_obs
+def test_walk_tiles_counter_covers_every_rounds_grid():
+    """`index_walk_tiles_total` counts each in-place top-k call's grid
+    steps: scored (listed tiles) plus skipped is rounds x grid length."""
+    eng = QueryEngine(P, band_rows=4, cache_entries=0)
+    eng.add_dense(X)
+    eng.topk(QUERIES, 5)
+    layout = eng._banded_layout()
+    grid = layout.matrix.shape[0] // 4  # 4-row tiles: the band size
+
+    def counts():
+        snap = eng.obs_snapshot()
+        tiles = snap["index_walk_tiles_total"]
+        return (snap["index_walk_rounds_total"], tiles["state=scored"],
+                tiles["state=skipped"])
+
+    before = counts()
+    eng.topk(QUERIES, 5)
+    eng.topk(X[-3:], 2)
+    rounds, scored, skipped = (a - b for a, b in zip(counts(), before))
+    assert rounds >= 2 and scored > 0 and skipped > 0
+    assert scored + skipped == rounds * grid
+
+
+@requires_obs
+def test_walk_compiles_one_program_per_query_bucket():
+    """Flushes whose queries sit in different weight ranges (so their
+    rounds hold different bands and rows) add no compiled program once
+    their query bucket has been served: the walk's program depends on
+    the bucket, the layout's size and k alone."""
+    eng = QueryEngine(P, band_rows=4, cache_entries=0)
+    eng.add_dense(X)
+    order = np.argsort(np.count_nonzero(X, axis=1))
+    light, heavy, mixed = X[order[:5]], X[order[-5:]], X[order[::13][:5]]
+    reg = obs.get_registry()
+
+    def compiles():
+        return reg.snapshot().get("jax_compiles_total", {}).get(
+            "phase=backend_compile", 0)
+
+    eng.topk(light, 5)  # the 8-row bucket's first flush compiles
+    before = compiles()
+    for q in (heavy, mixed, light[::-1]):
+        eng.topk(q, 5)
+    assert compiles() == before
+    eng.topk(np.concatenate([light, heavy, mixed]), 5)  # the 16-row bucket
+    before = compiles()
+    eng.topk(np.concatenate([heavy, mixed, light]), 5)
+    assert compiles() == before
 
 
 @requires_obs

@@ -11,7 +11,10 @@ Contracts pinned here:
     documents — the bit-identity contract belongs to core.allpairs, whose
     jnp path the serving layer uses off-TPU);
   * core.allpairs.topk_rows mode="pallas" (the TPU serving route) agrees
-    with its jnp tile loop.
+    with its jnp tile loop;
+  * the in-place entry (`topk_select_tiles`, the band walk's) scores only
+    the listed tiles, breaks ties by rank, never returns rank -1, and its
+    jnp twin in core.allpairs returns the same bits.
 """
 
 import numpy as np
@@ -147,3 +150,130 @@ def test_topk_rows_pallas_mode_matches_jnp(metric):
         np.testing.assert_array_equal(pv, jv)
     else:
         np.testing.assert_allclose(pv, jv, rtol=1e-5, atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# the in-place entry: listed tiles of a resident matrix, rank tie-break
+# ---------------------------------------------------------------------------
+
+TILE = 8
+
+
+def _layout(n, p, w, dead=(), seed=0):
+    """A resident matrix of p rows (n real ones), its weight row, and a
+    rank row from a shuffled id order with `dead` positions at -1."""
+    b = _rows(p, w)
+    ids = np.random.default_rng(seed).permutation(n)
+    rank, pos_of_rank = allpairs.key_rank(ids, p)
+    rank[list(dead)] = -1
+    weights = np.asarray(allpairs.packing.popcount_rows(b))[None, :]
+    return b, jnp.asarray(weights), rank, pos_of_rank
+
+
+def _tiles(listed, p, bn=TILE):
+    tiles = np.zeros(p // bn, np.int32)
+    tiles[: len(listed)] = listed
+    return jnp.asarray(tiles)
+
+
+def _in_place(a, b, weights, rank, listed, k, metric, count=None,
+              bn=TILE):
+    return topk_kernel.topk_select_tiles(
+        a, b, weights, jnp.asarray(rank[None, :]),
+        _tiles(listed, b.shape[0], bn),
+        len(listed) if count is None else count, k, metric=metric, d=D,
+        bq=8, bn=bn, interpret=True)
+
+
+def _listed_rows(listed, rank):
+    """The listed tiles' live rows, arranged in rank order."""
+    rows = np.concatenate([np.arange(t * TILE, (t + 1) * TILE)
+                           for t in listed])
+    rows = rows[rank[rows] >= 0]
+    return rows[np.argsort(rank[rows])]
+
+
+@pytest.mark.parametrize("metric", ["cham", "hamming"])
+def test_in_place_entry_equals_ref_over_listed_rows_in_rank_order(metric):
+    """A shuffled tile list over a padded layout with dead rows: the
+    kernel returns the ranks of the reference's k-best over the listed
+    live rows laid out in rank order."""
+    n, p = 61, 80
+    b, weights, rank, _ = _layout(n, p, 8, dead=(3, 17, 40))
+    a = _rows(13, 8)
+    listed = [6, 1, 9, 3, 0, 7]
+    kv, kr = _in_place(a, b, weights, rank, listed, 7, metric)
+    rows = _listed_rows(listed, rank)
+    rv, ri = topk_select_ref(a, b[rows], 7, d=D, metric=metric)
+    np.testing.assert_array_equal(np.asarray(kr), rank[rows[np.asarray(ri)]])
+    _check(metric, kv, ri, rv, ri)
+
+
+@pytest.mark.parametrize("metric", ["cham", "hamming"])
+def test_in_place_ties_across_tiles_resolve_to_lower_rank(metric):
+    """Copies of each row in three tiles, ranked so that the copy in the
+    LATER column has the LOWER rank: every tie resolves by rank."""
+    base = _rows(TILE, 8)
+    b = jnp.concatenate([base, base, base], axis=0)
+    p = b.shape[0]
+    rank = np.arange(p, dtype=np.int32)[::-1].copy()  # rank falls with column
+    weights = jnp.asarray(allpairs.packing.popcount_rows(b))[None, :]
+    kv, kr = _in_place(base, b, weights, rank, [0, 2, 1], 3, metric)
+    kr = np.asarray(kr)
+    # the three copies of query row i sit at columns i, i+8, i+16, at
+    # distance zero from it; lower rank = higher column comes first
+    cols = np.arange(TILE)[:, None] + np.array([16, 8, 0])[None, :]
+    np.testing.assert_array_equal(kr, rank[cols])
+    np.testing.assert_array_equal(np.asarray(kv)[:, 0], np.asarray(kv)[:, 2])
+
+
+@pytest.mark.parametrize("metric", ["cham", "hamming"])
+def test_in_place_never_returns_rank_minus_one(metric):
+    """Rows ranked -1 (dead, padding) never come back, even when the
+    listed tiles hold fewer than k live rows: the rest read (inf, -1)."""
+    n, p = 20, 32
+    dead = (0, 2, 5, 6, 9, 11, 13, 14)
+    b, weights, rank, _ = _layout(n, p, 8, dead=dead)
+    a = _rows(5, 8)
+    listed = [1, 0, 3]  # 8 + 8 rows with 8 dead, and a tile of padding
+    kv, kr = _in_place(a, b, weights, rank, listed, 12, metric)
+    kv, kr = np.asarray(kv), np.asarray(kr)
+    rows = _listed_rows(listed, rank)
+    assert len(rows) == 8
+    np.testing.assert_array_equal(np.sort(kr[:, :8], axis=1),
+                                  np.tile(np.sort(rank[rows]), (5, 1)))
+    np.testing.assert_array_equal(kr[:, 8:], -1)
+    assert np.isinf(kv[:, 8:]).all() and np.isfinite(kv[:, :8]).all()
+
+
+@pytest.mark.parametrize("metric", ["cham", "hamming"])
+def test_in_place_steps_past_count_change_nothing(metric):
+    """Entries of the tile list past `count` are never scored, whatever
+    tiles they name."""
+    n, p = 64, 64
+    b, weights, rank, _ = _layout(n, p, 8)
+    a = _rows(9, 8)
+    short = _in_place(a, b, weights, rank, [4, 2], 5, metric)
+    padded = _in_place(a, b, weights, rank, [4, 2, 0, 7, 5], 5, metric,
+                       count=2)
+    for x, y in zip(short, padded):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("metric", ["cham", "hamming"])
+def test_in_place_jnp_twin_equals_kernel(metric):
+    """core.allpairs' jnp twin of the in-place entry (the CPU walk) returns
+    what the kernel returns, bit for bit, ranks and values (at tiles of
+    128 rows: on the CPU, XLA rounds the estimator differently at some
+    narrow tile widths)."""
+    n, p, bn = 1100, 2048, 128
+    b, weights, rank, _ = _layout(n, p, 8, dead=(7, 70, 700, 701))
+    a = _rows(16, 8)
+    listed = [6, 3, 8, 0, 5]
+    kv, kr = _in_place(a, b, weights, rank, listed, 6, metric, bn=bn)
+    tv, tr = allpairs._topk_tiles_impl(
+        a, b, jnp.asarray(rank[None, :]), _tiles(listed, p, bn),
+        np.int32(len(listed)), k=6, tile=bn, width=bn, metric=metric,
+        mode="popcount", d=D)
+    np.testing.assert_array_equal(np.asarray(tr), np.asarray(kr))
+    np.testing.assert_array_equal(np.asarray(tv), np.asarray(kv))
