@@ -1,13 +1,16 @@
-"""Serving cells: open-loop requests through `serve.FrontDoor`.
+"""Serving cells, and the `open_loop` kind: requests offered at a fixed
+rate through `serve.FrontDoor`.
 
-Set-up draws the corpus on the device, sketches it with the program's own
-`sketch_sparse_jit` (the `cabin_build_sparse` kernel on a TPU) and stores
-it with `QueryEngine.add_packed`, with no host copy; one call builds the
-serving layout.  Queries are drawn in set-up too: fresh rows from a stream
+`ServingCell` is every serving kind's set-up, warm-up and check; a kind
+names its load (`make_load`).  Set-up draws the corpus on the device,
+sketches it with the program's own `sketch_sparse_jit` (the
+`cabin_build_sparse` kernel on a TPU) and stores it with
+`QueryEngine.add_packed`, with no host copy; one call builds the serving
+layout.  Queries are drawn in set-up too: fresh rows from a stream
 disjoint from the corpus's, or stored rows whose entries are dropped with
-a fresh mask per request.  Warm-up offers the same load on other queries
-until two rounds in a row compile nothing.  The window then offers requests at
-the traffic's fixed `rate_per_s` (bench/sweep.py finds the rate the
+a fresh mask per request.  Warm-up offers the load on other queries until
+two rounds in a row compile nothing.  Here the window then offers requests
+at the traffic's fixed `rate_per_s` (bench/sweep.py finds the rate the
 system sustains); a request due before the window closes is awaited and
 counted.
 
@@ -29,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 import corpus as corpus_mod
+import faults
 import reference as ref
 from corpus import Stream
 
@@ -60,12 +64,29 @@ def make_params(cfg: dict, seed: int):
 
 
 def make_engine(cfg: dict, params):
+    """The engine with every setting of the configuration's `engine`."""
     from repro.index import QueryEngine
-    e = cfg["engine"]
     return QueryEngine(params, metric=cfg["sketch"]["metric"],
-                       block=e["block"], band_rows=e["band_rows"],
-                       cache_entries=e["cache_entries"],
-                       merge_ratio=e["merge_ratio"], keep_raw=e["keep_raw"])
+                       **cfg["engine"])
+
+
+def one_chip(kind: str, workload: dict) -> None:
+    """Kinds that run one unsharded engine refuse a cell of more chips."""
+    if int(workload["chips"]) != 1:
+        raise ValueError(f"kind {kind!r} runs one engine on one chip; cell "
+                         f"{workload['name']!r} asks for "
+                         f"{workload['chips']} chips")
+
+
+def tiny_config(cfg: dict) -> dict:
+    """A configuration shrunk for the CPU tests: a few thousand short rows
+    at d=512, flushes of up to 4 rows."""
+    cfg["corpus"].update(rows=3000, n_dims=5000, nnz_mean=30.0, nnz_max=100,
+                         zipf_draws=512, gen_rows=1024)
+    cfg["sketch"]["sketch_dim"] = 512
+    cfg["engine"].update(block=256, band_rows=128)
+    cfg["frontdoor"]["max_batch_rows"] = 4
+    return cfg
 
 
 def corpus_batches(cfg: dict):
@@ -134,6 +155,12 @@ class Queries:
         return idx, val
 
 
+def submit(door, op: str, param, q):
+    if op == "radius":
+        return door.submit("radius", q, r=param)
+    return door.submit("topk", q, k=param)
+
+
 class OpenLoad:
     """Open-loop load on one front door: requests due at fixed times,
     `rate` a second, submitted by one thread; a second thread collects the
@@ -162,11 +189,6 @@ class OpenLoad:
         gaps = np.random.default_rng([self.seed, 13, int(warm)]).permutation(
             gaps) * (seconds / gaps.sum())
         return np.cumsum(gaps) - gaps
-
-    def _submit(self, q):
-        if self.op == "radius":
-            return self.door.submit("radius", q, r=self.param)
-        return self.door.submit("topk", q, k=self.param)
 
     def run(self, seconds: float, first_n: int = 0, warm: bool = False):
         """Offer the load for `seconds` on request numbers first_n,
@@ -215,7 +237,7 @@ class OpenLoad:
                 late.append(time.perf_counter() - t_due)
                 try:
                     with self.span("bench.submit"):
-                        req = self._submit(q)
+                        req = submit(self.door, self.op, self.param, q)
                 except RejectedError:
                     req = None
                 pending.put((first_n + i, t_due, req, q))
@@ -228,7 +250,17 @@ class OpenLoad:
 
 
 class ServingCell:
-    def __init__(self, cfg: dict, traffic: dict, seed: int, clock, span):
+    """A serving cell (bench/run.py's cell protocol) short of its load: a
+    kind subclasses it as `Cell` and builds the load in `make_load`."""
+
+    CONTROLS = ("bf16", "hash16")
+    FAULTS = {"half_batch": faults.half_batch,
+              "answer_altered": faults.answer_altered}
+    OFFERS_RATE = False
+
+    def __init__(self, workload: dict, cfg: dict, traffic: dict, seed: int,
+                 clock, span):
+        one_chip(traffic["kind"], workload)
         self.cfg = cfg
         self.traffic = traffic
         self.seed = seed
@@ -262,9 +294,7 @@ class ServingCell:
         self.phases["layout_s"] = time.perf_counter() - t
         self.queries = Queries(cfg, self.traffic, seed, self.corpus, sources)
         self.door = FrontDoor(self.engine, **cfg["frontdoor"])
-        self.load = OpenLoad(self.door, self.op, self.param, self.queries,
-                             float(self.traffic["rate_per_s"]), seed,
-                             self.span)
+        self.load = self.make_load()
         t = time.perf_counter()
         self.warmup()
         self.phases.update(warmup_s=time.perf_counter() - t,
@@ -321,14 +351,26 @@ class ServingCell:
 
     # -- window ---------------------------------------------------------------
 
-    def window(self, seconds: float):
+    @classmethod
+    def tiny(cls, cfg: dict, traffic: dict):
+        tiny_config(cfg)
+        if traffic["op"] == "radius":
+            cfg["serving"]["radius"] = 24.45  # inside a cluster of distances
+        for key, small in (("sources", 64), ("fresh_pool", 256)):
+            if key in traffic:
+                traffic[key] = small
+        traffic["check_sample"] = 16
+        return cfg, traffic
+
+    def make_load(self):
+        raise NotImplementedError
+
+    def window(self, ctx, seconds: float):
         self.t0, self.records = self.load.run(seconds)
-        late = np.array(self.load.late_s) * 1e3
-        self.phases.update(
-            late_p95_ms=float(np.percentile(late, 95)),
-            late_max_ms=float(late.max()),
-            refused=sum(1 for r in self.records if not r[6]))
-        return self.t0, self.records
+        self.phases["refused"] = sum(1 for r in self.records if not r[6])
+        ctx.requests[self.op] = [(r[1], r[2], r[3]) for r in self.records]
+        return (self.t0, max(r[2] for r in self.records), len(self.records),
+                sum(1 for r in self.records if not r[3]))
 
     def obs(self):
         return self.engine.obs
@@ -439,3 +481,26 @@ def _bf16():
     return ml_dtypes.bfloat16
 
 
+class Cell(ServingCell):
+    """Open loop at the traffic's `rate_per_s`."""
+
+    OFFERS_RATE = True
+
+    @classmethod
+    def tiny(cls, cfg: dict, traffic: dict):
+        cfg, traffic = super().tiny(cfg, traffic)
+        # faster than a tiny flush on the CPU, so flushes hold several rows
+        traffic["rate_per_s"] = 40.0
+        return cfg, traffic
+
+    def make_load(self):
+        return OpenLoad(self.door, self.op, self.param, self.queries,
+                        float(self.traffic["rate_per_s"]), self.seed,
+                        self.span)
+
+    def window(self, ctx, seconds: float):
+        out = super().window(ctx, seconds)
+        late = np.array(self.load.late_s) * 1e3
+        self.phases.update(late_p95_ms=float(np.percentile(late, 95)),
+                           late_max_ms=float(late.max()))
+        return out

@@ -1,4 +1,4 @@
-"""Rolling-ingest cells: a news archive that takes new articles all the
+"""The `rolling_ingest` kind: a news archive that takes new articles all the
 time and keeps the newest `rows` searchable.
 
 Set-up stores the corpus as the serving cells do (device draw, the
@@ -24,16 +24,28 @@ import numpy as np
 import jax
 
 import corpus as corpus_mod
+import faults
 import reference as ref
-from cell_serving import load_corpus, make_engine, make_params, padded_rows
+from cell_open_loop import (load_corpus, make_engine, make_params, one_chip,
+                            padded_rows, tiny_config)
 from corpus import Stream
 
 SKETCH_SAMPLE = 512
 WARMUP_MAX_STEPS = 64
 
 
-class IngestCell:
-    def __init__(self, cfg: dict, traffic: dict, seed: int, clock, span):
+class Cell:
+    """bench/run.py's cell protocol for rolling ingest."""
+
+    CONTROLS = ("hash16",)
+    FAULTS = {"state_unchanged": faults.state_unchanged,
+              "half_batch": faults.half_batch,
+              "answer_altered": faults.answer_altered}
+    OFFERS_RATE = False
+
+    def __init__(self, workload: dict, cfg: dict, traffic: dict, seed: int,
+                 clock, span):
+        one_chip(traffic["kind"], workload)
         self.cfg = cfg
         self.traffic = traffic
         self.seed = seed
@@ -43,6 +55,11 @@ class IngestCell:
         self.batch_rows = int(traffic["batch_rows"])
         self.keep = int(cfg["corpus"]["rows"])
         self.compact_share = float(traffic["compact_share"])
+
+    @classmethod
+    def tiny(cls, cfg: dict, traffic: dict):
+        traffic.update(batch_rows=256, pool_batches=3)
+        return tiny_config(cfg), traffic
 
     def setup(self) -> None:
         cfg, seed = self.cfg, self.seed
@@ -93,8 +110,9 @@ class IngestCell:
         self.step()
         jax.block_until_ready(self.engine.store.sk_buf)
 
-    def window(self, seconds: float):
-        """Steps until `seconds` have passed; returns (t0, t1, rows)."""
+    def window(self, ctx, seconds: float):
+        """Steps until `seconds` have passed; the readers get the rows
+        acknowledged and the seconds they took."""
         self.first_step = self.steps
         self.t0 = time.perf_counter()
         t_end = self.t0 + seconds
@@ -104,7 +122,8 @@ class IngestCell:
         self.t1 = time.perf_counter()
         self.window_steps = self.steps - self.first_step
         self.window_rows = self.window_steps * self.batch_rows
-        return self.t0, self.t1, self.window_rows
+        ctx.ingest = (self.window_rows, self.t1 - self.t0)
+        return self.t0, self.t1, self.window_steps, 0
 
     def obs(self):
         return self.engine.obs

@@ -6,9 +6,9 @@ seeds, in one process, each with the program's numbers and the controls'.
 
 Each seed is a whole run of the cell (set-up, window, reference) as
 bench/run.py makes it; the result line of each seed also carries, under
-"controls", what the cell's controls read on the same sample (bench/
-cell_serving.py and bench/cell_ingest.py name them).  The benchmark's own
-runs never run the controls.
+"controls", what the cell's controls read on the same sample (its cell
+class names them in CONTROLS).  The benchmark's own runs never run the
+controls.
 """
 
 from __future__ import annotations
@@ -20,9 +20,6 @@ import sys
 import time
 
 import run
-
-CONTROLS = {"open_loop": ("bf16", "hash16"), "rolling_ingest": ("hash16",)}
-
 
 def main() -> int:
     ap = argparse.ArgumentParser()
@@ -38,7 +35,7 @@ def main() -> int:
     for seed in args.seeds:
         res = run.run_cell(args.workload, seed, args.seconds, False,
                            spec=spec, device=device,
-                           controls=CONTROLS[traffic["kind"]],
+                           controls=run.cell_class(traffic["kind"]).CONTROLS,
                            t_start=t_start)
         print(json.dumps({"seed": seed, **res}), flush=True)
         gc.collect()

@@ -5,10 +5,34 @@
 
 A cell (an entry of BENCHMARK.json's `workloads`) names a configuration,
 bench/configs/<config>.json, and a traffic mix, bench/traffic/<mix>.json.
-The mix's `kind` picks the load: `open_loop` (bench/cell_serving.py) or
-`rolling_ingest` (bench/cell_ingest.py).  Every metric of BENCHMARK.json is
-read by bench/metrics/<metric>.py, whose `read(ctx)` returns a number or
-None when the cell has nothing for it to read.
+The mix's `kind` names the class that runs the cell: `Cell` of the module
+bench/cell_<kind>.py, found by that name alone.  Every metric of
+BENCHMARK.json is read by bench/metrics/<metric>.py, whose `read(ctx)`
+returns a number or None when the cell has nothing for it to read.  So a
+new deployment enters as new files: a configuration, a traffic mix, a
+kind's module where no kind fits, and readers.
+
+The cell protocol (bench/readings.py, bench/sweep.py and bench/tests use
+it too):
+
+  Cell(workload, cfg, traffic, seed, clock, span)  the workload's entry
+      of BENCHMARK.json (its `chips` too), the configuration, the mix, the
+      run's seed, a CompileClock and the span factory (bench.* spans)
+  setup()            everything before the window: data, state, warm-up
+  obs()              the program's obs registry (`snapshot()`)
+  window(ctx, seconds) -> (t0, t1, attempted, failed)  the measured
+      window; fills what its readers read in `ctx` (Ctx.requests,
+      Ctx.ingest)
+  release()          frees the program's state before the reference runs
+  check(controls) -> {"program": {name: (value, limit)}, control: {...}}
+  CONTROLS           names of the controls `check` can read
+  FAULTS             {name: planter(monkeypatch)}: the faults the cell can
+                     have, planted under the harness by its self-tests
+  tiny(cfg, traffic) -> (cfg, traffic)  (classmethod) the cell shrunk for
+                     the CPU tests
+  OFFERS_RATE        whether `cell.load.rate` sets an offered rate that
+                     bench/sweep.py may sweep
+  phases, n_checked  (optional) set-up and check figures for the log
 
 A run: set-up (corpus drawn on the device from --seed, sketched and stored
 through the program, every shape warmed from the persistent compile cache
@@ -33,8 +57,10 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -45,6 +71,7 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 CACHE_DIR = BENCH / ".jax_cache"
 TRACE_DIR = BENCH / ".trace"
+METRICS = BENCH / "metrics"
 sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 
 EXIT_NO_CHIP = 3
@@ -63,12 +90,16 @@ def load_spec(root: Path = ROOT) -> dict:
     return json.loads((root / "BENCHMARK.json").read_text())
 
 
-def cell_files(spec: dict, name: str) -> tuple[dict, dict, dict]:
-    """(workload entry, configuration, traffic mix) of cell `name`."""
+def workload_entry(spec: dict, name: str) -> dict:
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
-    wl = cells[name]
+    return cells[name]
+
+
+def cell_files(spec: dict, name: str) -> tuple[dict, dict, dict]:
+    """(workload entry, configuration, traffic mix) of cell `name`."""
+    wl = workload_entry(spec, name)
     conf = {c["name"]: c for c in spec["configs"]}[wl["config"]]
     cfg = json.loads((ROOT / conf["file"]).read_text())
     traffic = json.loads((BENCH / "traffic" / f"{wl['traffic']}.json")
@@ -91,7 +122,7 @@ def cell_metrics(spec: dict, name: str, trace: bool) -> list[dict]:
 
 
 def load_reader(metric: str):
-    path = BENCH / "metrics" / f"{metric}.py"
+    path = METRICS / f"{metric}.py"
     mod_spec = importlib.util.spec_from_file_location(
         f"bench_metric_{metric.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(mod_spec)
@@ -204,14 +235,15 @@ def memory_peak(chips: int) -> int:
     return max(peaks)
 
 
-def make_cell(cfg: dict, traffic: dict, seed: int, clock, span):
-    if traffic["kind"] == "open_loop":
-        from cell_serving import ServingCell
-        return ServingCell(cfg, traffic, seed, clock, span)
-    if traffic["kind"] == "rolling_ingest":
-        from cell_ingest import IngestCell
-        return IngestCell(cfg, traffic, seed, clock, span)
-    raise ValueError(f"unknown traffic kind {traffic['kind']!r}")
+KIND = re.compile(r"[a-z][a-z0-9_]*")
+
+
+def cell_class(kind: str):
+    """`Cell` of the module cell_<kind>, found on the import path (bench/
+    first)."""
+    if not KIND.fullmatch(kind):
+        raise ValueError(f"traffic kind {kind!r} is not a module name")
+    return importlib.import_module(f"cell_{kind}").Cell
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -226,9 +258,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     from clock import CompileClock
 
     log = log or (lambda *a: print(*a, file=sys.stderr, flush=True))
-    wl, cfg0, traffic0 = cell_files(spec, workload)
-    cfg = cfg or cfg0
-    traffic = traffic or traffic0
+    wl = workload_entry(spec, workload)
+    if cfg is None or traffic is None:
+        _, cfg0, traffic0 = cell_files(spec, workload)
+        cfg, traffic = cfg or cfg0, traffic or traffic0
     chips = int(wl["chips"])
     metrics = cell_metrics(spec, workload, trace)
     ctx = Ctx(workload, cfg, traffic, device)
@@ -236,7 +269,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     span = (jax.profiler.TraceAnnotation if trace
             else (lambda name: contextlib.nullcontext()))
 
-    cell = make_cell(cfg, traffic, seed, clock, span)
+    cell = cell_class(traffic["kind"])(wl, cfg, traffic, seed, clock, span)
     cell.setup()
     ctx.obs = ObsDelta(cell.obs())
     ctx.setup_s = time.perf_counter() - t_start
@@ -247,19 +280,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         opts.host_tracer_level = 2
         jax.profiler.start_trace(str(TRACE_DIR), profiler_options=opts)
     with span("bench.window"):
-        if traffic["kind"] == "open_loop":
-            t0, records = cell.window(seconds)
-            ctx.window_t0 = t0
-            ctx.requests[cell.op] = [(r[1], r[2], r[3]) for r in records]
-            attempted = len(records)
-            failed = sum(1 for r in records if not r[3])
-            window_end = max(r[2] for r in records)
-        else:
-            t0, t1, rows = cell.window(seconds)
-            ctx.window_t0 = t0
-            ctx.ingest = (rows, t1 - t0)
-            attempted, failed = cell.window_steps, 0
-            window_end = t1
+        t0, window_end, attempted, failed = cell.window(ctx, seconds)
+    ctx.window_t0 = t0
     if trace:
         jax.profiler.stop_trace()
     ctx.obs.close()

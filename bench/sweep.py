@@ -3,9 +3,9 @@
 
     python3 bench/sweep.py --workload <cell> --seed <n> --seconds 10 --rates 30 40 50 60
 
-One set-up of the cell (as bench/run.py makes it), then the cell's open-loop
-load at each offered rate in turn, on the window's queries, each step
-drained before the next.  Prints one JSON line per rate: the rate answered
+One set-up of the cell (as bench/run.py makes it), then the cell's load at
+each offered rate in turn, on the window's queries, each step drained
+before the next: a cell whose class OFFERS_RATE.  Prints one JSON line per rate: the rate answered
 (as `radius_qps` and `topk_qps` count it), the latency percentiles from due
 time to answer, the mean rows of a flush, refusals, and how late the
 generator submitted.  No answer is checked.  Serving cells offer a fixed
@@ -35,14 +35,15 @@ def main() -> int:
     args = ap.parse_args()
     spec = run.load_spec()
     wl, cfg, traffic = run.cell_files(spec, args.workload)
-    if traffic["kind"] != "open_loop":
+    cls = run.cell_class(traffic["kind"])
+    if not cls.OFFERS_RATE:
         ap.error(f"{args.workload} offers no request rate")
     run.enable_cache()
     device = run.device_info(int(wl["chips"]))
     from clock import CompileClock
     clock = CompileClock()
-    cell = run.make_cell(cfg, traffic, args.seed, clock,
-                         lambda name: contextlib.nullcontext())
+    cell = cls(wl, cfg, traffic, args.seed, clock,
+               lambda name: contextlib.nullcontext())
     cell.setup()
     print(json.dumps({"setup_s": time.perf_counter() - run.T_START,
                       "device": device, **cell.phases}), flush=True)

@@ -3,9 +3,11 @@
     JAX_PLATFORMS=cpu python -m pytest bench/tests
 
 Each cell runs end to end (set-up, warm-up, window, reference check) on a
-shrunken copy of its configuration; the controls and planted faults must
-come out not correct; the trace reducer and the roofline byte counts are
-checked against hand counts; and bench/run.py refuses to run off a TPU.
+shrunken copy of its configuration; the controls and planted faults its
+cell class names must come out not correct; a kind the harness has never
+seen runs from a file of its own; the trace reducer and the roofline byte
+counts are checked against hand counts; and bench/run.py refuses to run
+off a TPU.
 """
 
 from __future__ import annotations
@@ -15,6 +17,7 @@ import json
 import os
 import subprocess
 import sys
+import textwrap
 from pathlib import Path
 
 import numpy as np
@@ -33,25 +36,16 @@ CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 SECONDS = 0.5
 
 
+def cell_class(workload: str):
+    return run.cell_class(run.cell_files(run.load_spec(), workload)[2]["kind"])
+
+
 def tiny(workload: str, **traffic_over):
-    """The cell's files, shrunk: a few thousand short rows at d=512."""
+    """The cell's files, shrunk by its cell class."""
     spec = run.load_spec()
     _, cfg, traffic = run.cell_files(spec, workload)
-    cfg, traffic = copy.deepcopy(cfg), copy.deepcopy(traffic)
-    cfg["corpus"].update(rows=3000, n_dims=5000, nnz_mean=30.0, nnz_max=100,
-                         zipf_draws=512, gen_rows=1024)
-    cfg["sketch"]["sketch_dim"] = 512
-    cfg["engine"].update(block=256, band_rows=128)
-    if traffic.get("op") == "radius":
-        cfg["serving"]["radius"] = 24.45  # inside a cluster of distances
-    if traffic["kind"] == "open_loop":
-        # faster than a tiny flush on the CPU, so flushes hold several rows
-        traffic.update(rate_per_s=40.0, check_sample=16)
-    cfg["frontdoor"]["max_batch_rows"] = 4
-    for key, small in (("sources", 64), ("fresh_pool", 256),
-                       ("batch_rows", 256), ("pool_batches", 3)):
-        if key in traffic:
-            traffic[key] = small
+    cfg, traffic = cell_class(workload).tiny(copy.deepcopy(cfg),
+                                             copy.deepcopy(traffic))
     traffic.update(traffic_over)
     return spec, cfg, traffic
 
@@ -81,15 +75,8 @@ def test_cell_runs_correct_end_to_end(workload):
 
 
 def _controls():
-    """(workload, control) for every control a cell has: bfloat16
-    distances where the cell serves distances, a 16-bit hash everywhere."""
-    out = []
-    for w in WORKLOADS:
-        kind = run.cell_files(run.load_spec(), w)[2]["kind"]
-        if kind == "open_loop":
-            out.append((w, "bf16"))
-        out.append((w, "hash16"))
-    return out
+    """(workload, control) for every control a cell's class names."""
+    return [(w, c) for w in WORKLOADS for c in cell_class(w).CONTROLS]
 
 
 @pytest.mark.parametrize("workload,control", _controls())
@@ -99,114 +86,128 @@ def test_control_is_not_correct(workload, control):
     assert not res["controls"][control]["correct"], res["controls"]
 
 
-def _planted(monkeypatch, fault: str):
-    """Break the timed path underneath the harness."""
-    from repro.index import QueryEngine
-    from repro.index.store import SketchStore
-
-    if fault == "state_unchanged":
-        real = SketchStore.add
-
-        def add(self, packed, n_valid=None):  # ids handed out, rows dropped
-            ids = np.arange(self._next_id, self._next_id
-                            + (packed.shape[0] if n_valid is None
-                               else n_valid), dtype=np.int64)
-            if getattr(self, "_bench_loaded", False):
-                self._next_id = int(ids[-1]) + 1
-                return ids
-            return real(self, packed, n_valid)
-        monkeypatch.setattr(SketchStore, "add", add)
-        real_sync = QueryEngine.sync_layout
-
-        def sync(self):  # after set-up, the store stops taking rows
-            self.store._bench_loaded = True
-            return real_sync(self)
-        monkeypatch.setattr(QueryEngine, "sync_layout", sync)
-        real_remove = QueryEngine.remove
-
-        def remove(self, ids):
-            self.store._bench_loaded = True
-            return real_remove(self, np.asarray(ids)[
-                np.isin(ids, self.store.ids())])
-        monkeypatch.setattr(QueryEngine, "remove", remove)
-    elif fault == "half_batch":
-        real = QueryEngine.topk
-
-        def topk(self, queries, k):  # the second half answers as the first
-            idx, val = queries
-            h = max(1, len(idx) // 2)
-            ids, d = real(self, (idx[:h], val[:h]), k)
-            rep = np.resize(np.arange(h), len(idx))
-            return ids[rep], d[rep]
-        monkeypatch.setattr(QueryEngine, "topk", topk)
-        real_r = QueryEngine.radius
-
-        def radius(self, queries, r):
-            idx, val = queries
-            h = max(1, len(idx) // 2)
-            hits = real_r(self, (idx[:h], val[:h]), r)
-            return [hits[i % h] for i in range(len(idx))]
-        monkeypatch.setattr(QueryEngine, "radius", radius)
-        real_a = QueryEngine.add_sparse
-
-        def add_sparse(self, indices, values):
-            h = len(indices) // 2
-            ids = real_a(self, indices[:h], values[:h])
-            rest = np.arange(ids[-1] + 1, ids[-1] + 1 + len(indices) - h)
-            self.store._next_id = int(rest[-1]) + 1
-            return np.concatenate([ids, rest])
-        monkeypatch.setattr(QueryEngine, "add_sparse", add_sparse)
-        real_rm = QueryEngine.remove
-        monkeypatch.setattr(QueryEngine, "remove", lambda self, ids: real_rm(
-            self, np.asarray(ids)[np.isin(ids, self.store.ids())]))
-    elif fault == "answer_altered":
-        real = QueryEngine.topk
-
-        def topk(self, queries, k):
-            ids, d = real(self, queries, k)
-            ids = ids.copy()
-            ids[0, 0] = (ids[0, 0] + 1) % len(self)
-            return ids, d
-        monkeypatch.setattr(QueryEngine, "topk", topk)
-        real_r = QueryEngine.radius
-
-        def radius(self, queries, r):
-            hits = real_r(self, queries, r)
-            hits[0] = np.union1d(hits[0], [(hits[0][0] + 1) % len(self)
-                                           if len(hits[0]) else 0])
-            return hits
-        monkeypatch.setattr(QueryEngine, "radius", radius)
-        real_a = QueryEngine.add_sparse
-
-        def add_sparse(self, indices, values):
-            values = np.array(values)
-            values[0, :] = 0  # the first row of each batch loses its words
-            return real_a(self, indices, values)
-        monkeypatch.setattr(QueryEngine, "add_sparse", add_sparse)
-
-
 def _faults():
-    """(workload, fault) for every fault a cell can have: a serving cell
-    holds no state that a window step changes."""
-    out = []
-    for w in WORKLOADS:
-        kind = run.cell_files(run.load_spec(), w)[2]["kind"]
-        faults = ["half_batch", "answer_altered"]
-        if kind == "rolling_ingest":
-            faults.insert(0, "state_unchanged")
-        out += [(w, f) for f in faults]
-    return out
+    """(workload, fault) for every fault a cell's class names (bench/
+    faults.py plants the program's)."""
+    return [(w, f) for w in WORKLOADS for f in cell_class(w).FAULTS]
 
 
 @pytest.mark.parametrize("workload,fault", _faults())
 def test_planted_fault_is_not_correct(monkeypatch, workload, fault):
-    _planted(monkeypatch, fault)
+    cell_class(workload).FAULTS[fault](monkeypatch)
     res = run_tiny(workload, check_sample=10_000)
     assert not res["correct"], res["checks"]
 
 
+TOY_CELL = '''
+import time
+
+import numpy as np
+
+
+def answer(x):
+    return x * x
+
+
+def plant_wrong_square(monkeypatch):
+    import cell_toy_square
+    monkeypatch.setattr(cell_toy_square, "answer", lambda x: x * x + 1)
+
+
+class Cell:
+    CONTROLS = ("off_by_one",)
+    FAULTS = {"wrong_square": plant_wrong_square}
+    OFFERS_RATE = False
+
+    def __init__(self, workload, cfg, traffic, seed, clock, span):
+        self.chips = workload["chips"]
+        self.n = int(traffic["requests"])
+        self.seed = seed
+
+    @classmethod
+    def tiny(cls, cfg, traffic):
+        return cfg, dict(traffic, requests=50)
+
+    def setup(self):
+        self.xs = np.random.default_rng(self.seed).integers(0, 1000, self.n)
+
+    def obs(self):
+        return self
+
+    def snapshot(self):
+        return {}
+
+    def window(self, ctx, seconds):
+        t0 = time.perf_counter()
+        self.out, recs = [], []
+        for x in self.xs:
+            t = time.perf_counter()
+            self.out.append(answer(int(x)))
+            recs.append((t, time.perf_counter() + 1e-6, True))
+        ctx.requests["square"] = recs
+        return t0, recs[-1][1], len(recs), 0
+
+    def release(self):
+        pass
+
+    def check(self, controls=()):
+        want = self.xs.astype(np.int64) ** 2
+
+        def wrong(got):
+            return int(np.count_nonzero(np.asarray(got) != want))
+        out = {"program": {"squares_wrong": (wrong(self.out), 0),
+                           "chips_wrong": (int(self.chips != 1), 0)}}
+        if "off_by_one" in controls:
+            out["off_by_one"] = {"squares_wrong": (wrong(want + 1), 0)}
+        return out
+'''
+
+
+def test_a_new_kind_runs_from_files_alone(tmp_path, monkeypatch):
+    """A kind, a reader and a cell the harness has never seen, each in a
+    file of its own: the run reports the reader's metric and the kind's
+    checks, its control and its planted fault come out not correct."""
+    (tmp_path / "cell_toy_square.py").write_text(TOY_CELL)
+    metrics = tmp_path / "metrics"
+    metrics.mkdir()
+    (metrics / "squares_per_s.py").write_text(textwrap.dedent('''
+        def read(ctx):
+            return ctx.rate("square")
+    '''))
+    (metrics / "setup_s.py").write_text(
+        (run.METRICS / "setup_s.py").read_text())
+    monkeypatch.syspath_prepend(str(tmp_path))
+    monkeypatch.setattr(run, "METRICS", metrics)
+    spec = copy.deepcopy(run.load_spec())
+    spec["workloads"].append({"name": "toy", "config": "toy",
+                              "traffic": "toy", "chips": 1, "why": "toy"})
+    spec["end_to_end"].append({"name": "squares_per_s", "unit": "1/s",
+                               "better": "higher", "bound": 0.05,
+                               "source": "host_clock", "workloads": ["toy"]})
+    cls = run.cell_class("toy_square")
+    cfg, traffic = cls.tiny({}, {"kind": "toy_square", "requests": 10**6})
+
+    def go(controls=()):
+        return run.run_cell("toy", 7, SECONDS, False, spec=spec, device=CPU,
+                            cfg=cfg, traffic=traffic, controls=controls,
+                            log=lambda *a: None)
+
+    res = go(controls=cls.CONTROLS)
+    assert res["correct"] and res["attempted"] == 50 and res["failed"] == 0
+    assert set(res["metrics"]) == {"squares_per_s", "setup_s"}
+    assert res["metrics"]["squares_per_s"]["value"] > 0
+    assert res["checks"] == {"squares_wrong": {"value": 0, "limit": 0},
+                             "chips_wrong": {"value": 0, "limit": 0}}
+    assert res["controls"]["off_by_one"]["squares_wrong"]["value"] == 50
+    assert not res["controls"]["off_by_one"]["correct"]
+    cls.FAULTS["wrong_square"](monkeypatch)
+    res = go()
+    assert not res["correct"]
+    assert res["checks"]["squares_wrong"]["value"] == 50
+
+
 def test_arrivals_are_the_same_multiset_for_every_seed():
-    from cell_serving import OpenLoad
+    from cell_open_loop import OpenLoad
     a = OpenLoad(None, "topk", 10, None, 46.0, 1, None).due_offsets(20.0, False)
     b = OpenLoad(None, "topk", 10, None, 46.0, 2**31 + 9, None).due_offsets(
         20.0, False)
